@@ -137,6 +137,15 @@ def _task_phase(phase_state: Dict[str, Any], phase: str,
             or "fanout"}
 
 
+def _served_planes(planes) -> Optional[str]:
+    """A fan-out response's ``_data_plane``: the distinct data planes
+    its shards (or its retriever legs) reported, joined with "+" — each
+    shard reports its own query trace's label (batch | dense |
+    dense_device), so the response and the latency histograms agree."""
+    got = sorted({q for p in planes if p for q in p.split("+")})
+    return "+".join(got) or None
+
+
 import logging
 
 logger = logging.getLogger(__name__)
@@ -1281,16 +1290,15 @@ class TransportSearchAction:
             if fused_ctx is not None:
                 hit = fused_ctx.pop("hit", None)
                 if hit is not None:
-                    resp = {**copy.deepcopy(hit),
-                            "took": int((time.monotonic() - t0) * 1000)}
-                    # observable end-to-end: the histogram entry lands
-                    # under (class x "cached"). The response itself is
-                    # byte-identical to the RPC fan-out's, modulo took —
-                    # and modulo the _data_plane marker a mesh-served
-                    # original would carry (stripped at fill; the
-                    # established mesh golden contract is "modulo
-                    # took/_data_plane")
+                    # observable end-to-end: the histogram entry and
+                    # the response's _data_plane both say "cached"; the
+                    # rest is byte-identical to the fan-out's, modulo
+                    # took (the original's _data_plane is stripped at
+                    # fill)
                     ctrace.data_plane = "cached"
+                    resp = {**copy.deepcopy(hit),
+                            "took": int((time.monotonic() - t0) * 1000),
+                            "_data_plane": "cached"}
                     ctrace.add_span("request_cache_hit",
                                     time.monotonic_ns() - entry_ns)
                     ctrace.finish()
@@ -1815,6 +1823,10 @@ class TransportSearchAction:
                             return   # the phase completed without us
                         if err is None:
                             target["node"] = node  # fetch follows query
+                            if isinstance(resp, dict):
+                                phase_state.setdefault(
+                                    "shard_planes", []).append(
+                                        resp.get("data_plane"))
                             round_cb({"resp": resp}, None)
                             return
                         # a cancelled task must abort the whole search,
@@ -2139,7 +2151,7 @@ class TransportSearchAction:
                         shards[f] += int(sub.get(f, 0))
                     timed_out = timed_out or bool(
                         (ranked or {}).get("timed_out"))
-                on_done({
+                resp = {
                     "took": int((time.monotonic() - t0) * 1000),
                     "timed_out": timed_out,
                     "_shards": shards,
@@ -2150,7 +2162,12 @@ class TransportSearchAction:
                              "max_score": (out_hits[0]["_score"]
                                            if out_hits else None),
                              "hits": out_hits},
-                }, None)
+                }
+                plane = _served_planes((ranked or {}).get("_data_plane")
+                                       for ranked in results)
+                if plane:
+                    resp["_data_plane"] = plane
+                on_done(resp, None)
 
             self.rrf_fuser.submit(doc_lists, len(first_hit),
                                   rank_constant, finalize)
@@ -2536,8 +2553,10 @@ class TransportSearchAction:
                  if r is not None])
         if phase_state["failures"]:
             resp["_shards"]["failures"] = phase_state["failures"]
-        if phase_state.get("data_plane"):
-            resp["_data_plane"] = phase_state["data_plane"]
+        plane = phase_state.get("data_plane") or \
+            _served_planes(phase_state.get("shard_planes", ()))
+        if plane:
+            resp["_data_plane"] = plane
         trace = phase_state.get("trace")
         if trace is not None:
             # the routing verdict labels the coordinator histogram entry:

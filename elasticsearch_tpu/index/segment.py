@@ -26,7 +26,6 @@ while segments grow/merge (SURVEY.md §7 hard part #3).
 from __future__ import annotations
 
 import itertools
-import math
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
@@ -407,24 +406,33 @@ class SegmentBuilder:
     # -- builders per kind ------------------------------------------------
 
     def _build_postings(self, fname: str, n_docs: int) -> PostingsField:
+        # the token stream (term id, doc, position) in document order;
+        # term ids number the terms in first-seen order
         terms: Dict[str, int] = {}
-        # per term: dict doc -> tf, and doc -> [positions]
-        tf_map: List[Dict[int, int]] = []
-        pos_map: List[Dict[int, List[int]]] = []
-        doc_lens = np.zeros(n_docs, np.float32)
+        tids: List[int] = []
+        positions: List[int] = []
+        counts = np.zeros(n_docs, np.int64)
         for local, d in enumerate(self.docs):
             pf = d.fields.get(fname)
             if pf is None or pf.terms is None:
                 continue
-            doc_lens[local] = len(pf.terms)
-            for tok in pf.terms:
-                tid = terms.setdefault(tok.term, len(terms))
-                if tid == len(tf_map):
-                    tf_map.append({})
-                    pos_map.append({})
-                tf_map[tid][local] = tf_map[tid].get(local, 0) + 1
-                pos_map[tid].setdefault(local, []).append(tok.position)
-        return _pack_postings(terms, tf_map, pos_map, doc_lens)
+            toks = pf.terms
+            counts[local] = len(toks)
+            tids.extend([terms.setdefault(t.term, len(terms)) for t in toks])
+            positions.extend([t.position for t in toks])
+        t = np.asarray(tids, np.int64)
+        d = np.repeat(np.arange(n_docs, dtype=np.int64), counts)
+        # stable by term: docs stay ascending and positions in token
+        # order within each (term, doc) entry
+        order = np.argsort(t, kind="stable")
+        t, d = t[order], d[order]
+        first = np.ones(len(t), bool)
+        first[1:] = (t[1:] != t[:-1]) | (d[1:] != d[:-1])
+        starts = np.flatnonzero(first)
+        tfs = np.diff(np.append(starts, len(t)))
+        return _pack_postings(terms, t[starts], d[starts], tfs, tfs,
+                              np.asarray(positions, np.int32)[order],
+                              counts.astype(np.float32))
 
     def _build_keywords(self, fname: str, n_docs: int) -> KeywordField:
         terms: Dict[str, int] = {}
@@ -477,17 +485,22 @@ class SegmentBuilder:
 
     def _build_features(self, fname: str, n_docs: int) -> FeaturesField:
         feats: Dict[str, int] = {}
-        weight_map: List[Dict[int, float]] = []
+        fids: List[int] = []
+        weights: List[float] = []
+        counts = np.zeros(n_docs, np.int64)
         for local, d in enumerate(self.docs):
             pf = d.fields.get(fname)
             if pf is None or pf.features is None:
                 continue
-            for fkey, w in pf.features.items():
-                fid = feats.setdefault(fkey, len(feats))
-                if fid == len(weight_map):
-                    weight_map.append({})
-                weight_map[fid][local] = w
-        return _pack_features(feats, weight_map)
+            counts[local] = len(pf.features)
+            fids.extend([feats.setdefault(k, len(feats))
+                         for k in pf.features])
+            weights.extend(pf.features.values())
+        f = np.asarray(fids, np.int64)
+        order = np.argsort(f, kind="stable")    # docs ascending per feature
+        docs = np.repeat(np.arange(n_docs, dtype=np.int64), counts)
+        return _pack_features(feats, f[order], docs[order],
+                              np.asarray(weights, np.float32)[order])
 
     def _build_geo(self, fname: str, n_docs: int) -> np.ndarray:
         arr = np.full((n_docs, 2), np.nan, np.float64)
@@ -562,65 +575,57 @@ def postings_from_token_matrix(tokens: np.ndarray,
     )
 
 
-def _pack_postings(terms: Dict[str, int], tf_map: List[Dict[int, int]],
-                   pos_map: List[Dict[int, List[int]]],
-                   doc_lens: np.ndarray) -> PostingsField:
+def _pack_postings(terms: Dict[str, int], e_term: np.ndarray,
+                   e_doc: np.ndarray, e_tf: np.ndarray, e_npos: np.ndarray,
+                   pos_flat: np.ndarray, doc_lens: np.ndarray
+                   ) -> PostingsField:
+    """The block layout from one entry per (term, doc), sorted by term
+    then doc (ascending, like Lucene): ``e_tf`` its term frequency,
+    ``e_npos`` how many of ``pos_flat`` (in entry order) are its
+    positions. Every term gets >= 1 block; pad slots hold doc -1."""
     n_terms = len(terms)
-    doc_freq = np.zeros(max(n_terms, 1), np.int32)
-    term_block_start = np.zeros(max(n_terms, 1), np.int32)
-    term_block_count = np.zeros(max(n_terms, 1), np.int32)
-
-    blocks_docs: List[np.ndarray] = []
-    blocks_tfs: List[np.ndarray] = []
-    block_term: List[int] = []
-    pos_counts: List[int] = []   # positions per entry, in block-entry order
-    pos_flat: List[int] = []
-
-    for tid in range(n_terms):
-        entries = sorted(tf_map[tid].items())  # by doc id (ascending, like Lucene)
-        doc_freq[tid] = len(entries)
-        docs = np.fromiter((e[0] for e in entries), np.int32, len(entries))
-        tfs = np.fromiter((e[1] for e in entries), np.float32, len(entries))
-        n_blocks = max(1, math.ceil(len(entries) / BLOCK))
-        term_block_start[tid] = len(blocks_docs)
-        term_block_count[tid] = n_blocks
-        padded = n_blocks * BLOCK
-        d = np.full(padded, -1, np.int32)
-        t = np.zeros(padded, np.float32)
-        d[: len(docs)] = docs
-        t[: len(tfs)] = tfs
-        blocks_docs.extend(d.reshape(n_blocks, BLOCK))
-        blocks_tfs.extend(t.reshape(n_blocks, BLOCK))
-        block_term.extend([tid] * n_blocks)
-
-        pm = pos_map[tid]
-        for i in range(padded):
-            if i < len(docs):
-                plist = pm.get(int(docs[i]), [])
-                pos_flat.extend(plist)
-                pos_counts.append(len(plist))
-            else:
-                pos_counts.append(0)
-
-    if blocks_docs:
-        block_docs = np.stack(blocks_docs)
-        block_tfs = np.stack(blocks_tfs)
-    else:
-        block_docs = np.full((1, BLOCK), -1, np.int32)
-        block_tfs = np.zeros((1, BLOCK), np.float32)
-        block_term = [0]
-        pos_counts = [0] * BLOCK
-    block_max_tf = block_tfs.max(axis=1)
-    pos_offsets = np.zeros(len(pos_counts) + 1, np.int32)
-    pos_offsets[1:] = np.cumsum(np.asarray(pos_counts, np.int64)).astype(np.int32)
+    if n_terms == 0:
+        return PostingsField(
+            terms=terms,
+            block_docs=np.full((1, BLOCK), -1, np.int32),
+            block_tfs=np.zeros((1, BLOCK), np.float32),
+            block_term=np.zeros(1, np.int32),
+            block_max_tf=np.zeros(1, np.float32),
+            term_block_start=np.zeros(1, np.int32),
+            term_block_count=np.zeros(1, np.int32),
+            doc_freq=np.zeros(1, np.int32),
+            doc_lens=doc_lens,
+            sum_doc_len=float(doc_lens.sum()),
+            pos_offsets=np.zeros(BLOCK + 1, np.int32),
+            pos_flat=np.zeros(0, np.int32),
+        )
+    e_term = np.asarray(e_term, np.int64)
+    doc_freq = np.bincount(e_term, minlength=n_terms).astype(np.int32)
+    n_blocks = np.maximum(1, -(-doc_freq // BLOCK)).astype(np.int32)
+    term_block_start = np.zeros(n_terms, np.int64)
+    np.cumsum(n_blocks[:-1], out=term_block_start[1:])
+    first_entry = np.zeros(n_terms, np.int64)
+    np.cumsum(doc_freq[:-1], out=first_entry[1:])
+    slot = term_block_start[e_term] * BLOCK + \
+        (np.arange(len(e_term)) - first_entry[e_term])
+    n_slots = int(n_blocks.sum()) * BLOCK
+    block_docs = np.full(n_slots, -1, np.int32)
+    block_docs[slot] = e_doc
+    block_tfs = np.zeros(n_slots, np.float32)
+    block_tfs[slot] = e_tf
+    pos_counts = np.zeros(n_slots, np.int64)
+    pos_counts[slot] = e_npos
+    pos_offsets = np.zeros(n_slots + 1, np.int32)
+    pos_offsets[1:] = np.cumsum(pos_counts).astype(np.int32)
+    block_tfs = block_tfs.reshape(-1, BLOCK)
     return PostingsField(
         terms=terms,
-        block_docs=block_docs,
+        block_docs=block_docs.reshape(-1, BLOCK),
         block_tfs=block_tfs,
-        block_term=np.asarray(block_term, np.int32),
-        block_max_tf=block_max_tf.astype(np.float32),
-        term_block_start=term_block_start,
-        term_block_count=term_block_count,
+        block_term=np.repeat(np.arange(n_terms, dtype=np.int32), n_blocks),
+        block_max_tf=block_tfs.max(axis=1).astype(np.float32),
+        term_block_start=term_block_start.astype(np.int32),
+        term_block_count=n_blocks,
         doc_freq=doc_freq,
         doc_lens=doc_lens,
         sum_doc_len=float(doc_lens.sum()),
@@ -645,41 +650,44 @@ def _pack_keywords(terms: Dict[str, int], per_doc: List[List[int]]) -> KeywordFi
     return KeywordField(terms, np.asarray(values, np.int32), offsets, doc_freq, term_list)
 
 
-def _pack_features(feats: Dict[str, int], weight_map: List[Dict[int, float]]) -> FeaturesField:
+def _pack_features(feats: Dict[str, int], e_feat: np.ndarray,
+                   e_doc: np.ndarray, e_w: np.ndarray) -> FeaturesField:
+    """The block layout from one entry per (feature, doc), sorted by
+    feature then doc; every feature gets >= 1 block."""
     n_feats = len(feats)
-    doc_freq = np.zeros(max(n_feats, 1), np.int32)
-    feat_block_start = np.zeros(max(n_feats, 1), np.int32)
-    feat_block_count = np.zeros(max(n_feats, 1), np.int32)
-    blocks_docs: List[np.ndarray] = []
-    blocks_w: List[np.ndarray] = []
-    for fid in range(n_feats):
-        entries = sorted(weight_map[fid].items())
-        doc_freq[fid] = len(entries)
-        docs = np.fromiter((e[0] for e in entries), np.int32, len(entries))
-        ws = np.fromiter((e[1] for e in entries), np.float32, len(entries))
-        n_blocks = max(1, math.ceil(len(entries) / BLOCK))
-        feat_block_start[fid] = len(blocks_docs)
-        feat_block_count[fid] = n_blocks
-        padded = n_blocks * BLOCK
-        d = np.full(padded, -1, np.int32)
-        w = np.zeros(padded, np.float32)
-        d[: len(docs)] = docs
-        w[: len(ws)] = ws
-        blocks_docs.extend(d.reshape(n_blocks, BLOCK))
-        blocks_w.extend(w.reshape(n_blocks, BLOCK))
-    if blocks_docs:
-        block_docs = np.stack(blocks_docs)
-        block_w = np.stack(blocks_w)
-    else:
-        block_docs = np.full((1, BLOCK), -1, np.int32)
+    if n_feats == 0:
         block_w = np.zeros((1, BLOCK), np.float32)
+        return FeaturesField(
+            features=feats,
+            block_docs=np.full((1, BLOCK), -1, np.int32),
+            block_weights=block_w,
+            block_max_weight=block_w.max(axis=1),
+            feat_block_start=np.zeros(1, np.int32),
+            feat_block_count=np.zeros(1, np.int32),
+            doc_freq=np.zeros(1, np.int32),
+        )
+    e_feat = np.asarray(e_feat, np.int64)
+    doc_freq = np.bincount(e_feat, minlength=n_feats).astype(np.int32)
+    n_blocks = np.maximum(1, -(-doc_freq // BLOCK)).astype(np.int32)
+    block_start = np.zeros(n_feats, np.int64)
+    np.cumsum(n_blocks[:-1], out=block_start[1:])
+    first_entry = np.zeros(n_feats, np.int64)
+    np.cumsum(doc_freq[:-1], out=first_entry[1:])
+    slot = block_start[e_feat] * BLOCK + \
+        (np.arange(len(e_feat)) - first_entry[e_feat])
+    n_slots = int(n_blocks.sum()) * BLOCK
+    block_docs = np.full(n_slots, -1, np.int32)
+    block_docs[slot] = e_doc
+    block_w = np.zeros(n_slots, np.float32)
+    block_w[slot] = e_w
+    block_w = block_w.reshape(-1, BLOCK)
     return FeaturesField(
         features=feats,
-        block_docs=block_docs,
+        block_docs=block_docs.reshape(-1, BLOCK),
         block_weights=block_w,
         block_max_weight=block_w.max(axis=1).astype(np.float32),
-        feat_block_start=feat_block_start,
-        feat_block_count=feat_block_count,
+        feat_block_start=block_start.astype(np.int32),
+        feat_block_count=n_blocks,
         doc_freq=doc_freq,
     )
 
@@ -793,7 +801,17 @@ def _merge_postings(fname: str, segments: Sequence[Segment],
                 pos = pf.positions_for(term, int(doc))
                 if len(pos):
                     pos_map[tid][new] = pos.tolist()
-    return _pack_postings(terms, tf_map, pos_map, doc_lens)
+    e_term, e_doc, e_tf, e_npos, pos_flat = [], [], [], [], []
+    for tid, entries in enumerate(tf_map):
+        for doc, tf in sorted(entries.items()):
+            plist = pos_map[tid].get(doc, ())
+            e_term.append(tid)
+            e_doc.append(doc)
+            e_tf.append(tf)
+            e_npos.append(len(plist))
+            pos_flat.extend(plist)
+    return _pack_postings(terms, e_term, e_doc, e_tf, e_npos, pos_flat,
+                          doc_lens)
 
 
 def _merge_keywords(fname: str, segments: Sequence[Segment],
@@ -875,4 +893,10 @@ def _merge_features(fname: str, segments: Sequence[Segment],
                 new = int(m[doc])
                 if new >= 0:
                     weight_map[fid][new] = float(w)
-    return _pack_features(feats, weight_map)
+    e_feat, e_doc, e_w = [], [], []
+    for fid, entries in enumerate(weight_map):
+        for doc, w in sorted(entries.items()):
+            e_feat.append(fid)
+            e_doc.append(doc)
+            e_w.append(w)
+    return _pack_features(feats, e_feat, e_doc, e_w)

@@ -110,10 +110,11 @@ class _CacheTier:
         family (the search.plane.* application pattern): one attribute
         compare per request, a real parse only when the committed state
         changed."""
-        version = getattr(state, "version", None)
-        if version is not None and version == self._cfg_version:
+        from elasticsearch_tpu.utils.settings import state_settings_key
+        key = state_settings_key(state)
+        if key is not None and key == self._cfg_version:
             return
-        self._cfg_version = version
+        self._cfg_version = key
         was_enabled = self.enabled
         self._apply_settings(state)
         if was_enabled and not self.enabled:

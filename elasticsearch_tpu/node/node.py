@@ -152,17 +152,6 @@ class Node:
             # pjit program, RPC scatter-gather stays the fallback
             from elasticsearch_tpu.parallel.mesh_plane import MeshDataPlane
             self.mesh_plane = MeshDataPlane()
-            # explicit mesh opt-in: bring the backend up at BOOT so the
-            # first search finds the mesh ready instead of silently
-            # serving the RPC fallback until other compute initializes it
-            self.mesh_plane.warmup()
-        # search.mesh.warmup_at_boot: pay device first-init NOW (the
-        # mesh-sharded plane's mesh_ready() refuses to inside a search,
-        # so the first eligible search per process otherwise takes the
-        # RPC detour). Checked once more when the setting appears in a
-        # later committed state (dynamic settings land after boot).
-        self._mesh_warmed = False
-        self._maybe_mesh_warmup(self._applied_state())
         from elasticsearch_tpu.transport.remote import RemoteClusterService
         self.remote_clusters = RemoteClusterService(self)
         self.search_action = TransportSearchAction(
@@ -386,34 +375,13 @@ class Node:
         # shard that can't initialize) must not skip master housekeeping, and
         # vice versa (ClusterApplierService catches per-applier the same way)
         for applier in (self.reconciler.apply_cluster_state,
-                        self._master_housekeeping,
-                        self._maybe_mesh_warmup):
+                        self._master_housekeeping):
             try:
                 applier(state)
             except Exception:  # noqa: BLE001
                 logger.exception("applier %s failed for state v%s on %s",
                                  getattr(applier, "__name__", applier),
                                  state.version, self.node_id)
-
-    def _maybe_mesh_warmup(self, state: ClusterState) -> None:
-        """search.mesh.warmup_at_boot applier: the first state (boot or
-        committed) that carries the setting pays backend first-init via
-        MESH_PLANES.warmup() — once per process, counted in the
-        mesh_plane_warmups stat. Off by default: warmup blocks on device
-        init, which only a node explicitly opted into mesh serving
-        should pay at boot."""
-        # getattr: a committed-state applier can fire before __init__
-        # reaches the flag assignment
-        if getattr(self, "_mesh_warmed", False):
-            return
-        from elasticsearch_tpu.utils.settings import (
-            SEARCH_MESH_WARMUP_AT_BOOT, setting_from_state,
-        )
-        if not setting_from_state(state, SEARCH_MESH_WARMUP_AT_BOOT):
-            return
-        self._mesh_warmed = True
-        from elasticsearch_tpu.ops.device_segment import MESH_PLANES
-        MESH_PLANES.warmup()
 
     def _master_housekeeping(self, state: ClusterState) -> None:
         """On the elected master: clean up routing after membership changes
@@ -466,6 +434,13 @@ class Node:
         # warns, ESTPU_ENFORCE_BOOTSTRAP aborts startup on failure
         from elasticsearch_tpu.monitor import run_bootstrap_checks
         run_bootstrap_checks(self.indices_service.data_path)
+        # the device backend comes up here, once: a backend that cannot
+        # initialise fails the node's start, never a search later
+        import jax
+        devices = jax.devices()
+        logger.info("node %s serves on %d %s device(s) [%s]", self.node_id,
+                    len(devices), devices[0].platform,
+                    devices[0].device_kind)
         self.coordinator.start()
         self.ilm_service.start()
         self.slm_service.start()

@@ -770,13 +770,13 @@ class PlaneRegistry:
     def configure_from_state(self, state) -> None:
         """Refresh config from committed cluster settings. Re-parsing per
         query would tax the very hot path this module shrinks, so the
-        parse is memoized on the state version (settings only change
+        parse is memoized on the committed state (settings only change
         through a committed state)."""
-        version = getattr(state, "version", None)
-        if version is not None and \
-                version == getattr(self, "_cfg_version", None):
+        from elasticsearch_tpu.utils.settings import state_settings_key
+        key = state_settings_key(state)
+        if key is not None and key == getattr(self, "_cfg_version", None):
             return
-        self._cfg_version = version
+        self._cfg_version = key
         from elasticsearch_tpu.utils.settings import (
             SEARCH_PLANE_ENABLED, SEARCH_PLANE_MAX_BYTES,
             SEARCH_PLANE_MIN_SEGMENTS, SEARCH_PLANE_QUANTIZED,
@@ -1199,7 +1199,6 @@ class MeshPlaneRegistry:
             "mesh_plane_incremental_appends": 0,
             "mesh_plane_evictions": 0,
             "mesh_plane_miss_fallbacks": 0,
-            "mesh_plane_warmups": 0,
             "mesh_quantized_queries": 0,
             "mesh_quantized_mirror_builds": 0,
             "mesh_quantized_fallbacks": 0,
@@ -1212,11 +1211,11 @@ class MeshPlaneRegistry:
     # -- config ---------------------------------------------------------
 
     def configure_from_state(self, state) -> None:
-        version = getattr(state, "version", None)
-        if version is not None and \
-                version == getattr(self, "_cfg_version", None):
+        from elasticsearch_tpu.utils.settings import state_settings_key
+        key = state_settings_key(state)
+        if key is not None and key == getattr(self, "_cfg_version", None):
             return
-        self._cfg_version = version
+        self._cfg_version = key
         from elasticsearch_tpu.utils.settings import (
             SEARCH_MESH_DP, SEARCH_MESH_ENABLED, SEARCH_MESH_HOSTS,
             SEARCH_MESH_MIN_SHARDS, setting_from_state,
@@ -1227,41 +1226,13 @@ class MeshPlaneRegistry:
         self.dp = setting_from_state(state, SEARCH_MESH_DP)
         spec = setting_from_state(state, SEARCH_MESH_HOSTS)
         try:
-            from elasticsearch_tpu.parallel.mesh import (
-                mesh_ready, parse_host_topology,
-            )
-            # never pay backend first-init here (the topology parse
-            # needs the device count): an uninitialized backend keeps
-            # the prior (None) topology until the mesh is warm
-            self.hosts = (parse_host_topology(spec)
-                          if spec and mesh_ready() else None)
+            from elasticsearch_tpu.parallel.mesh import parse_host_topology
+            self.hosts = parse_host_topology(spec) if spec else None
         except Exception:     # noqa: BLE001 — a bad spec disables the
             self.hosts = None  # hosts layer, never serving
 
     def available(self, n_shards: int) -> bool:
-        if not self.enabled or n_shards < max(1, self.min_shards):
-            return False
-        from elasticsearch_tpu.parallel.mesh import mesh_ready
-        return mesh_ready()
-
-    def warmup(self) -> bool:
-        """Pay backend first-init NOW (the ``search.mesh.warmup_at_boot``
-        setting, the legacy mesh plane's boot-time warmup): ``mesh_ready``
-        refuses to pay first-init inside a search, so without this the
-        FIRST eligible search per process always takes the RPC detour.
-        True (and counted) when this call actually initialized the
-        backend; False when it was already up or no backend exists."""
-        from elasticsearch_tpu.parallel.mesh import mesh_ready
-        import sys
-        if sys.modules.get("jax") is not None and mesh_ready():
-            return False
-        try:
-            import jax
-            jax.devices()
-        except Exception:  # noqa: BLE001 — no backend: stay on RPC
-            return False
-        self.stats["mesh_plane_warmups"] += 1
-        return True
+        return self.enabled and n_shards >= max(1, self.min_shards)
 
     # -- lookup / build -------------------------------------------------
 
@@ -1548,10 +1519,8 @@ class MeshPlaneRegistry:
                             "devices_per_host":
                                 int(self.hosts.devices_per_host),
                             "spec": self.hosts.spec}
-        from elasticsearch_tpu.parallel.mesh import mesh_ready
-        if mesh_ready():
-            import jax
-            out["n_devices"] = len(jax.devices())
+        import jax
+        out["n_devices"] = len(jax.devices())
         return out
 
     def residency_snapshot(self) -> Dict[str, Any]:

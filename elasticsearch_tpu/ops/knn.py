@@ -121,15 +121,24 @@ def knn_topk_batch_masked(matrix, norms, exists, live, queries, masks,
                          similarity)
 
 
+# Row floor of the padded query batch. XLA picks the [B, D] x [D, N]
+# dot's accumulation order by shape, so a solo query padded to B=1 and
+# the same query inside a wave padded to B=4 can differ in the last ulp;
+# one floor puts every batch of up to 8 queries through the same shape
+# ("solo is a batch of one" holds bit-for-bit).
+MIN_QUERY_ROWS = 8
+
+
 def pad_queries_pow2(queries) -> Tuple[np.ndarray, int]:
-    """Pad the query batch to a pow2 row count (zeros) so the jit cache
-    stays warm across batch sizes; returns (padded, n_real). One
-    implementation shared by the exact executor and the quantized plane
-    pass — their pads must stay in lockstep."""
+    """Pad the query batch to a pow2 row count of at least
+    ``MIN_QUERY_ROWS`` (zeros) so the jit cache stays warm across batch
+    sizes; returns (padded, n_real). One implementation shared by the
+    exact executor and the quantized plane pass — their pads must stay
+    in lockstep (``pad_mask_rows_pow2`` takes its row count from here)."""
     from elasticsearch_tpu.index.segment import next_pow2
     q_host = np.asarray(queries, np.float32)
     n_real = q_host.shape[0]
-    n_pad = next_pow2(max(n_real, 1), minimum=1)
+    n_pad = next_pow2(max(n_real, 1), minimum=MIN_QUERY_ROWS)
     if n_pad != n_real:
         q_host = np.concatenate(
             [q_host, np.zeros((n_pad - n_real, q_host.shape[1]),

@@ -28,6 +28,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from elasticsearch_tpu.search.device_profile import profiled_callable
@@ -57,19 +58,6 @@ def replicated(mesh: Mesh) -> NamedSharding:
 # ---------------------------------------------------------------------------
 # serving-tier mesh layout (mesh-sharded plane)
 # ---------------------------------------------------------------------------
-
-try:
-    from jax import shard_map
-except ImportError:   # pre-0.5 jax: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-    from functools import wraps as _wraps
-
-    @_wraps(_shard_map_legacy)
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_legacy(*args, **kwargs)
-
 
 _MESH_CACHE: Dict[Tuple, Mesh] = {}
 
@@ -184,21 +172,6 @@ def set_host_backend(backend) -> None:
 
 def host_backend():
     return _HOST_BACKEND
-
-
-def mesh_ready() -> bool:
-    """True when a jax backend is ALREADY initialized — mesh layout must
-    observe devices, never pay (or hang on) first-init inside a search
-    (the same never-pay guard as parallel/mesh_plane.py and monitor)."""
-    import sys
-    jax_mod = sys.modules.get("jax")
-    if jax_mod is None:
-        return False
-    try:
-        from jax._src import xla_bridge as _xb
-        return bool(_xb.backends_are_initialized())
-    except Exception:  # noqa: BLE001 — private API moved: assume the
-        return True    # pre-guard behavior (devices() below inits)
 
 
 def mesh_layout(n_shards: int, dp: int = 1, max_devices: int = 0,

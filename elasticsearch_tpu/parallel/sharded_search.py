@@ -36,19 +36,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:   # pre-0.5 jax: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-    from functools import wraps as _wraps
-
-    @_wraps(_shard_map_legacy)
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_legacy(*args, **kwargs)
 
 from elasticsearch_tpu.index.segment import BLOCK, next_pow2
 from elasticsearch_tpu.search.device_profile import profiled_callable

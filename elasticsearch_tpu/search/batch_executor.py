@@ -279,7 +279,7 @@ def _classify(req: Dict[str, Any], mappers) -> BatchSpec:
         if mappers.field_type(query.field) != "dense_vector":
             return dense_spec(req)
         opts = getattr(mapper, "index_options", None) or {}
-        if opts.get("type") not in (None, "ivf"):
+        if opts.get("type") not in (None, "ivf", "flat"):
             return dense_spec(req)   # unknown index type
         # filtered kNN batches: the filter becomes a per-query (or
         # shared) mask inside the batched matmul, exactly the dense
@@ -907,7 +907,12 @@ class ShardQueryBatcher:
             trace_class = telemetry.classify_body(req.get("body") or {})
         else:
             trace_class = _CLASS_OF_KIND.get(spec.kind, "other")
-        member.trace = SearchTrace(trace_class, "batch")
+        # the member's data plane, named once here: the response, the
+        # latency histograms and _tasks all read it off this trace —
+        # "dense" members execute per member on the host (query_shard),
+        # everything else rides the shared batched device program
+        member.trace = SearchTrace(
+            trace_class, "dense" if spec.kind == "dense" else "batch")
         member.trace.t0_ns = member.enqueued_ns
         if self.sts.task_manager is not None:
             member.task = self.sts.task_manager.register(
@@ -915,7 +920,8 @@ class ShardQueryBatcher:
                 f"shard query [{req['index']}][{req['shard']}]",
                 cancellable=True,
                 parent_task_id=req.get("task_id"))
-            member.task.status = {"phase": "queued", "data_plane": "batch"}
+            member.task.status = {"phase": "queued",
+                                  "data_plane": member.trace.data_plane}
         remaining = req.get("budget_remaining")
         if remaining is not None:
             member.deadline = scheduler.now() + float(remaining)
@@ -1152,7 +1158,9 @@ class ShardQueryBatcher:
         """Resolve every drained member, stamping successful responses
         with the shard ``took_ms`` (arrival -> delivery in scheduler
         time — what the coordinator subtracts from its round trip to
-        split wire from service) and the node pressure snapshot."""
+        split wire from service), the node pressure snapshot, and the
+        data plane that served the member (the coordinator names it in
+        the response's ``_data_plane``)."""
         pressure = self.node_pressure
         now = self._scheduler().now()
         # ONE drain-consistent snapshot (taken while the drain's members
@@ -1164,24 +1172,27 @@ class ShardQueryBatcher:
             if m.error is None and isinstance(m.result, dict):
                 took_ms = max((now - m.enqueued_at) * 1e3, 0.0)
                 m.result = {**m.result, "took_ms": round(took_ms, 3),
-                            "pressure": snap}
+                            "pressure": snap,
+                            "data_plane": m.trace.data_plane}
         pressure.in_flight = max(0, pressure.in_flight - len(members))
         for m in members:
             self._finish(m)
 
     def _set_phase(self, members: List[_Member], phase: str,
                    occupancy: Optional[int] = None,
-                   data_plane: str = "batch") -> None:
+                   data_plane: Optional[str] = None) -> None:
         """_tasks phase fidelity: a shard task shows its current
         sub-phase (queued -> query -> dispatch -> demux) instead of
         "query" for its whole life — occupancy-1 members included.
         ``occupancy`` (drain width) rides the status so the hot-spans
         sampler (GET /_nodes/hot_spans) can show which in-flight spans
-        share one device dispatch; ``data_plane`` is "dense_device" for
-        members whose aggs ride the drain-wide columns plane."""
+        share one device dispatch; the data plane is the member trace's,
+        or ``data_plane`` ("dense_device" for members whose aggs ride the
+        drain-wide columns plane)."""
         for m in members:
             if m.task is not None and m.error is None:
-                status = {"phase": phase, "data_plane": data_plane}
+                status = {"phase": phase,
+                          "data_plane": data_plane or m.trace.data_plane}
                 if occupancy:
                     status["occupancy"] = occupancy
                 m.task.status = status
@@ -1377,7 +1388,7 @@ class ShardQueryBatcher:
                 continue
             self._set_phase([u], "dispatch",
                             data_plane="dense_device"
-                            if ui in preset_by_ui else "batch")
+                            if ui in preset_by_ui else None)
             t0 = time.monotonic_ns()
             meta: Dict[str, Any] = {}
             try:

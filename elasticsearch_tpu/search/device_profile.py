@@ -75,10 +75,7 @@ def _tracer_type():
     """jax's Tracer type, resolved lazily (public path first)."""
     global _TRACER_TYPE
     if _TRACER_TYPE is None:
-        try:
-            from jax.core import Tracer
-        except Exception:  # noqa: BLE001 — moved in newer jax
-            from jax._src.core import Tracer
+        from jax.core import Tracer
         _TRACER_TYPE = Tracer
     return _TRACER_TYPE
 
@@ -101,7 +98,7 @@ class FamilyProfile:
     """One kernel family's observatory record."""
 
     __slots__ = ("name", "compiles", "cache_hits", "compile_ns_total",
-                 "compile_ns_max", "shapes", "execute", "execute_device",
+                 "compile_ns_max", "shapes", "execute",
                  "cost", "compile_marks", "storms")
 
     def __init__(self, name: str):
@@ -115,12 +112,6 @@ class FamilyProfile:
         self.shapes: Dict[str, int] = {}
         # shape-bucket label -> [ewma_ms, observations]
         self.execute: Dict[str, list] = {}
-        # shape-bucket label -> [ewma_ms, observations] from DEVICE
-        # execution events, populated only where the backend exposes a
-        # per-dispatch duration on result buffers (no device sync ever);
-        # empty on backends without the surface — the host EWMA above
-        # stays the authoritative fallback
-        self.execute_device: Dict[str, list] = {}
         # shape-bucket label -> {"flops": ..., "bytes_accessed": ...}
         self.cost: Dict[str, Dict[str, float]] = {}
         # recent compile times (monotonic seconds) for the storm window
@@ -166,11 +157,11 @@ class DeviceProfile:
         settings (``search.device_profile.storm_*``), memoized on the
         state version like the plane registries — the parse must not tax
         the per-search hot path it observes."""
-        version = getattr(state, "version", None)
-        if version is not None and \
-                version == getattr(self, "_cfg_version", None):
+        from elasticsearch_tpu.utils.settings import state_settings_key
+        key = state_settings_key(state)
+        if key is not None and key == getattr(self, "_cfg_version", None):
             return
-        self._cfg_version = version
+        self._cfg_version = key
         from elasticsearch_tpu.utils.settings import (
             SEARCH_DEVICE_PROFILE_SLOW_COMPILE,
             SEARCH_DEVICE_PROFILE_STORM_THRESHOLD,
@@ -242,24 +233,6 @@ class DeviceProfile:
             got[0] = EWMA_ALPHA * ms + (1 - EWMA_ALPHA) * got[0]
             got[1] += 1
 
-    def on_execute_device(self, family: str, label: str,
-                          dur_ns: int) -> None:
-        """A device-event execution duration (backend-reported, not
-        host-observed) for an already-compiled dispatch. Recorded beside
-        the host EWMA, never instead of it: the host figure keeps its
-        dispatch-cost meaning on every backend, the device figure only
-        exists where the runtime hands it over for free."""
-        fam = self.family(family)
-        got = fam.execute_device.get(label)
-        ms = dur_ns / 1e6
-        if got is None:
-            fam.execute_device[label] = [ms, 1]
-            while len(fam.execute_device) > MAX_BUCKETS_PER_FAMILY:
-                fam.execute_device.pop(next(iter(fam.execute_device)))
-        else:
-            got[0] = EWMA_ALPHA * ms + (1 - EWMA_ALPHA) * got[0]
-            got[1] += 1
-
     # -- surfaces ---------------------------------------------------------
 
     def total_compiles(self) -> int:
@@ -284,11 +257,6 @@ class DeviceProfile:
                     for label, (ewma, count)
                     in sorted(fam.execute.items())},
             }
-            if fam.execute_device:
-                families[name]["execute_device_ewma_ms"] = {
-                    label: {"ewma_ms": round(ewma, 4), "calls": count}
-                    for label, (ewma, count)
-                    in sorted(fam.execute_device.items())}
             if fam.cost:
                 families[name]["cost"] = {
                     label: {k: round(v, 1) for k, v in entry.items()}
@@ -302,10 +270,6 @@ class DeviceProfile:
                 f.storms for f in self._families.values()),
             "storm_threshold": self.storm_threshold,
             "storm_window_s": self.storm_window_s,
-            # True once any family recorded a backend-reported duration
-            # (operators read which timing semantics the EWMAs carry)
-            "device_events": any(f.execute_device
-                                 for f in self._families.values()),
         }
 
     def reset(self) -> None:
@@ -344,18 +308,6 @@ class ProfiledJit:
             if fn is not None else family
         self.__doc__ = fn.__doc__ if fn is not None else None
         self.__wrapped__ = fn if fn is not None else jitted
-        # per-INSTANCE shape mirror for the fallback compile detector:
-        # several wrappers can share one family (the masked/unmasked
-        # mesh variants, re-created factory kernels), but each has its
-        # own jit cache — a family-shared mirror would mask their
-        # first compiles from each other when _cache_size is absent.
-        # Populated ONLY on the fallback path (dead weight otherwise)
-        # and FIFO-bounded like the family maps.
-        self._seen_labels: Dict[str, None] = {}
-        # device-event probe state: None = unprobed, False = surface
-        # absent on this backend (probe once, never again), True =
-        # result buffers carry per-dispatch durations
-        self._device_events: Optional[bool] = None
         params: Tuple[str, ...] = ()
         if fn is not None:
             try:
@@ -369,14 +321,10 @@ class ProfiledJit:
     def lower(self, *args, **kwargs):
         return self._jitted.lower(*args, **kwargs)
 
-    def _cache_size(self) -> Optional[int]:
+    def _cache_size(self) -> int:
         """The jitted function's executable-cache size — the
-        authoritative compiled-or-not signal. Private jax surface, so
-        None (fall back to the shape mirror) when it moves."""
-        try:
-            return int(self._jitted._cache_size())
-        except Exception:  # noqa: BLE001 — private API moved
-            return None
+        authoritative compiled-or-not signal."""
+        return int(self._jitted._cache_size())
 
     # -- the profiled call ------------------------------------------------
 
@@ -420,36 +368,6 @@ class ProfiledJit:
         except Exception:  # noqa: BLE001 — estimates are best-effort
             return None
 
-    # candidate private surfaces for a backend-reported per-dispatch
-    # duration on result buffers (some accelerator runtimes attach one;
-    # CPU does not). Attribute reads only — the probe must NEVER
-    # block_until_ready or otherwise device-sync.
-    _DEVICE_EVENT_ATTRS = ("execution_duration_ns",
-                           "_execution_duration_ns")
-
-    def _device_event_ns(self, out) -> Optional[int]:
-        """Backend-reported device duration for this dispatch, or None.
-        Probes the first output leaf once: a backend without the surface
-        caches False and every later call costs a single flag check, so
-        the host-EWMA fallback path stays exactly as cheap as before."""
-        if self._device_events is False:
-            return None
-        leaf = out
-        while isinstance(leaf, (tuple, list)) and leaf:
-            leaf = leaf[0]
-        for name in self._DEVICE_EVENT_ATTRS:
-            try:
-                v = getattr(leaf, name)
-                v = v() if callable(v) else v
-                v = int(v)
-            except Exception:  # noqa: BLE001 — absent/moved surface
-                continue
-            if v > 0:
-                self._device_events = True
-                return v
-        self._device_events = False
-        return None
-
     def __call__(self, *args, **kwargs):
         reg = DEVICE_PROFILE
         if not reg.enabled:
@@ -465,27 +383,11 @@ class ProfiledJit:
         t0 = time.monotonic_ns()
         out = self._jitted(*args, **kwargs)
         dur_ns = time.monotonic_ns() - t0
-        after = self._cache_size()
-        if before is not None and after is not None:
-            compiled = after > before
-        else:
-            # bounded mirror: past the cap an evicted-then-recurring
-            # shape reads as a fresh compile (overcount, the safe
-            # direction for a DETECTOR — bounded memory outranks exact
-            # counts on a fallback path that only exists when the
-            # private cache-size surface is gone)
-            compiled = label not in self._seen_labels
-            self._seen_labels[label] = None
-            while len(self._seen_labels) > 4 * MAX_BUCKETS_PER_FAMILY:
-                self._seen_labels.pop(next(iter(self._seen_labels)))
-        if compiled:
+        if self._cache_size() > before:
             reg.on_compile(self.family, label, dur_ns,
                            self._cost_of(args, kwargs))
         else:
             reg.on_execute(self.family, label, dur_ns)
-            dev_ns = self._device_event_ns(out)
-            if dev_ns is not None:
-                reg.on_execute_device(self.family, label, dev_ns)
         return out
 
 

@@ -216,10 +216,7 @@ class MeshSearchExecutor:
                 return False
             MESH_PLANES.configure_from_state(state)
             if not MESH_PLANES.available(len(targets)):
-                TELEMETRY.count_fallback(
-                    telemetry.MESH_TOO_FEW_SHARDS
-                    if len(targets) < max(1, MESH_PLANES.min_shards)
-                    else telemetry.MESH_BACKEND_NOT_READY)
+                TELEMETRY.count_fallback(telemetry.MESH_TOO_FEW_SHARDS)
                 return False
             if state is not None:
                 from elasticsearch_tpu.xpack.searchable_snapshots import (

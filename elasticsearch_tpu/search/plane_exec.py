@@ -676,6 +676,14 @@ def _quantized_topk(part: PlaneVectors, vectors: np.ndarray, live,
             return None
 
 
+def maps_exact(mappers, field: str) -> bool:
+    """A field mapped ``index_options: {type: flat}`` asks for exact
+    brute force at any size: neither the IVF probe nor the int8 coarse
+    tier (whose margin is empirical) may serve it."""
+    opts = getattr(mappers.mapper(field), "index_options", None) or {}
+    return opts.get("type") == "flat"
+
+
 def plane_knn_winners(ctxs, part: PlaneVectors, field: str, specs,
                       k: int,
                       check_members: Optional[Callable[[], None]] = None,
@@ -744,7 +752,7 @@ def plane_knn_winners(ctxs, part: PlaneVectors, field: str, specs,
         with BREAKERS.breaker("request").limit_scope(
                 transient, "plane_knn"):
             got = None
-            if PLANES.quantized:
+            if PLANES.quantized and not maps_exact(ctxs[0].mappers, field):
                 got = _coarse_attempt(
                     "knn", len(exact_idx),
                     lambda: _quantized_topk(part, vectors[exact_idx],
@@ -1409,13 +1417,16 @@ def mesh_knn_winners(shard_ctxs, mpart, field: str, specs, k: int,
     n_sh = mpart.n_shards
 
     ctx0 = next((ctxs[0] for ctxs in shard_ctxs if ctxs), None)
+    opts = {}
     if ctx0 is not None:
         mapper = ctx0.mappers.mapper(field)
         opts = getattr(mapper, "index_options", None) or {}
         if opts.get("type") == "ivf":
             raise MeshFallback(
                 f"[{field}] is IVF-mapped: the per-shard probe serves")
-    for sub in mpart.subs:
+    # a "flat" field is exact at any size: no IVF route, no int8 tier
+    exact = opts.get("type") == "flat"
+    for sub in () if exact else mpart.subs:
         if sub is None:
             continue
         sizes = [s.n_docs for s in sub.segments
@@ -1428,7 +1439,10 @@ def mesh_knn_winners(shard_ctxs, mpart, field: str, specs, k: int,
         check_members()
     vectors = np.asarray([s.query_vector for s in specs], np.float32)
     dp = max(1, int(mpart.mesh.shape["dp"]))
-    n_q_pad = next_pow2(max(n_q, 1), minimum=1)
+    # the single-shard kNN pad's row floor: a slot's [B, D] x [D, N] dot
+    # then has the fan-out's shape, so mesh and RPC scores agree to the bit
+    from elasticsearch_tpu.ops.knn import MIN_QUERY_ROWS
+    n_q_pad = next_pow2(max(n_q, 1), minimum=MIN_QUERY_ROWS)
     n_q_pad = -(-n_q_pad // dp) * dp
     q_host = np.zeros((n_q_pad, vectors.shape[1]), np.float32)
     q_host[:n_q] = vectors
@@ -1526,7 +1540,7 @@ def mesh_knn_winners(shard_ctxs, mpart, field: str, specs, k: int,
                 return None
 
     got_q = None
-    if PLANES.quantized:
+    if PLANES.quantized and not exact:
         engages = _mesh_engages(mpart.subs, k_mesh)
         if engages is None:
             # counted on the stats surface here (the executor counts the

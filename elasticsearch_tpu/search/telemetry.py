@@ -57,7 +57,6 @@ from typing import Any, Dict, List, Optional
 # scatter-gather) and drain-time fallbacks (why a submitted fan-out was
 # handed back)
 MESH_DISABLED = "mesh_disabled"
-MESH_BACKEND_NOT_READY = "mesh_backend_not_ready"
 MESH_TOO_FEW_SHARDS = "mesh_too_few_shards"
 MESH_FROZEN_INDEX = "mesh_frozen_index"
 MESH_NOT_COLOCATED = "mesh_not_colocated"

@@ -412,16 +412,6 @@ SEARCH_MESH_HOSTS: Setting[str] = Setting.str_setting(
     "search.mesh.hosts", "",
     scope=Scope.CLUSTER, properties=Property.DYNAMIC)
 
-# pre-init the device backend when a node boots (the legacy mesh
-# plane's boot-time warmup): mesh_ready() refuses to pay first-init
-# inside a search, so without this the FIRST mesh-eligible search per
-# process always takes the RPC detour. Applied at boot from the node's
-# initial committed state and re-checked (once) when the setting later
-# appears in a committed state; counted as mesh_plane_warmups
-SEARCH_MESH_WARMUP_AT_BOOT: Setting[bool] = Setting.bool_setting(
-    "search.mesh.warmup_at_boot", False,
-    scope=Scope.CLUSTER, properties=Property.DYNAMIC)
-
 # Device observatory (search/device_profile.py) recompile-storm
 # detector, promoted from DEVICE_PROFILE.configure() module config to
 # dynamic cluster settings (the search.plane.* application pattern):
@@ -593,6 +583,18 @@ INDEX_SOFT_DELETES_RETENTION_OPS: Setting[int] = Setting.int_setting(
 INDEX_RETENTION_LEASE_PERIOD: Setting[float] = Setting.time_setting(
     "index.soft_deletes.retention_lease.period", "12h",
     scope=Scope.INDEX, properties=Property.DYNAMIC)
+
+
+def state_settings_key(state):
+    """What a settings memo keys on: the committed state's version AND
+    uuid. Versions repeat across clusters in one process (tests, the
+    in-process cluster), so a process-global registry memoized on the
+    version alone could keep another cluster's settings. None (no
+    version) = never memoize."""
+    version = getattr(state, "version", None)
+    if version is None:
+        return None
+    return version, getattr(state, "state_uuid", None)
 
 
 def setting_from_state(state, setting: Setting[T]) -> T:

@@ -439,10 +439,10 @@ def test_e2e_mesh_vs_fanout_byte_parity(seed):
                 lambda cb, b=body: client.search("m", copy.deepcopy(b),
                                                  cb))
             assert err is None, (body, err)
-            assert resp.get("_data_plane") is None
+            assert resp.get("_data_plane") == "batch"
             a = {k: v for k, v in mesh_resp.items()
                  if k not in ("took", "_data_plane")}
-            b = {k: v for k, v in resp.items() if k != "took"}
+            b = {k: v for k, v in resp.items() if k not in ("took", "_data_plane")}
             assert json.dumps(a, sort_keys=True) == \
                 json.dumps(b, sort_keys=True), body
         node = next(iter(cluster.nodes.values()))
@@ -480,9 +480,9 @@ def test_mesh_drain_memo_dedups_identical_members():
             resps.append(resp)
         # 4 identical members in one drain -> 1 execution + 3 memo hits
         assert ex.stats["memo_hits"] >= 3
-        ref = {k: v for k, v in resps[0].items() if k != "took"}
+        ref = {k: v for k, v in resps[0].items() if k not in ("took", "_data_plane")}
         for resp in resps[1:]:
-            got = {k: v for k, v in resp.items() if k != "took"}
+            got = {k: v for k, v in resp.items() if k not in ("took", "_data_plane")}
             assert json.dumps(got, sort_keys=True) == \
                 json.dumps(ref, sort_keys=True)
         # a duplicate's hits match a fresh solo mesh search exactly
@@ -530,10 +530,10 @@ def test_e2e_mesh_miss_fallback_identity(monkeypatch):
         resp2, err = cluster.call(
             lambda cb: client.search("m", copy.deepcopy(body), cb))
         assert err is None
-        assert resp2.get("_data_plane") is None
+        assert resp2.get("_data_plane") == "batch"
         a = {k: v for k, v in resp.items()
              if k not in ("took", "_data_plane")}
-        b = {k: v for k, v in resp2.items() if k != "took"}
+        b = {k: v for k, v in resp2.items() if k not in ("took", "_data_plane")}
         assert json.dumps(a, sort_keys=True) == \
             json.dumps(b, sort_keys=True)
         node = next(iter(cluster.nodes.values()))
@@ -575,10 +575,10 @@ def test_mesh_can_match_skipped_parity():
             {"persistent": {"search.mesh.enabled": False}}, cb))
         ref, err = cluster.call(
             lambda cb: client.search("m", copy.deepcopy(body), cb))
-        assert err is None and ref.get("_data_plane") is None
+        assert err is None and ref.get("_data_plane") == "batch"
         a = {k: v for k, v in resp.items()
              if k not in ("took", "_data_plane")}
-        b = {k: v for k, v in ref.items() if k != "took"}
+        b = {k: v for k, v in ref.items() if k not in ("took", "_data_plane")}
         assert json.dumps(a, sort_keys=True) == \
             json.dumps(b, sort_keys=True)
     finally:
@@ -750,10 +750,10 @@ def test_e2e_multihost_mesh_vs_fanout_byte_parity(seed):
                 lambda cb, b=body: client.search("m", copy.deepcopy(b),
                                                  cb))
             assert err is None, (body, err)
-            assert resp.get("_data_plane") is None
+            assert resp.get("_data_plane") == "batch"
             a = {k: v for k, v in mesh_resp.items()
                  if k not in ("took", "_data_plane")}
-            b = {k: v for k, v in resp.items() if k != "took"}
+            b = {k: v for k, v in resp.items() if k not in ("took", "_data_plane")}
             assert json.dumps(a, sort_keys=True) == \
                 json.dumps(b, sort_keys=True), body
         assert TELEMETRY.fallbacks.get("unknown", 0) == before_unknown
@@ -799,7 +799,7 @@ def test_multihost_host_loss_typed_fallback():
         finally:
             ex._execute = orig_execute
         assert err is None, err
-        assert resp2.get("_data_plane") is None
+        assert resp2.get("_data_plane") == "batch"
         lost = TELEMETRY.fallbacks.get("mesh_host_lost", 0) - \
             before.get("mesh_host_lost", 0)
         assert lost >= 1, TELEMETRY.fallbacks
@@ -817,7 +817,7 @@ def test_multihost_host_loss_typed_fallback():
             lambda cb: client.search("m", copy.deepcopy(body2), cb),
             max_time=180.0)
         assert err is None, err
-        assert resp3.get("_data_plane") is None
+        assert resp3.get("_data_plane") == "batch"
         assert resp2["hits"] == resp3["hits"]
     finally:
         cluster.stop()
@@ -855,10 +855,10 @@ def test_e2e_dfs_mesh_parity(seed):
                     "m", copy.deepcopy(b), cb,
                     search_type="dfs_query_then_fetch"))
             assert err is None, (body, err)
-            assert resp.get("_data_plane") is None
+            assert resp.get("_data_plane") == "dense"   # DFS overrides
             a = {k: v for k, v in mesh_resp.items()
                  if k not in ("took", "_data_plane")}
-            b = {k: v for k, v in resp.items() if k != "took"}
+            b = {k: v for k, v in resp.items() if k not in ("took", "_data_plane")}
             assert json.dumps(a, sort_keys=True) == \
                 json.dumps(b, sort_keys=True), body
     finally:

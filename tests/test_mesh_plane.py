@@ -52,7 +52,7 @@ def test_mesh_path_serves_eligible_search(cluster):
     rpc, err = cluster.call(lambda cb: client.search(
         "mesh", {**q, "track_total_hits": True}, cb))
     _ok(rpc, err)
-    assert "_data_plane" not in rpc
+    assert rpc["_data_plane"] == "dense"   # unbounded totals: per member
     # the DEFAULT totals threshold is mesh-served with EXACT counts
     # (counts-then-skip over the sharded program)
     default, err = cluster.call(lambda cb: client.search("mesh", q, cb))
@@ -157,7 +157,7 @@ def test_ineligible_queries_fall_back_to_rpc(cluster):
         resp, err = cluster.call(lambda cb, b=body: client.search(
             "fb", b, cb))
         _ok(resp, err)
-        assert "_data_plane" not in resp, body
+        assert resp.get("_data_plane") not in ("mesh", "mesh_plane"), body
 
 
 def test_mesh_serves_bool_should(cluster):

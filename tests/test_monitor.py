@@ -50,20 +50,34 @@ def test_bootstrap_checks(tmp_path, monkeypatch):
     monitor.run_bootstrap_checks(str(blocked))   # warns, returns
 
 
-def test_device_stats_survive_private_api_removal(monkeypatch):
-    """ADVICE r5 low: the backends_are_initialized guard lives in
-    jax._src — private, free to move in any jax upgrade. When the lookup
-    breaks, device_stats must fall through to jax.devices() (mirroring
-    mesh_plane's ready=True fallback), not silently report no devices
-    forever while a backend is live."""
+@pytest.mark.parametrize("env_dir", [False, True], ids=["unset", "set"])
+def test_compile_cache_placement(monkeypatch, tmp_path, env_dir):
+    """The persistent compilation cache lives where
+    JAX_COMPILATION_CACHE_DIR says (JAX reads it; the helper sets no
+    other path), else at the checkout's fixed .jax_cache."""
     import jax
 
-    jax.devices()   # ensure the backend is LIVE (conftest pins cpu)
-    from jax._src import xla_bridge
-    # simulate the private API vanishing in a future jax
-    monkeypatch.delattr(xla_bridge, "backends_are_initialized")
-    d = monitor.device_stats()
-    assert len(d["devices"]) > 0   # pre-fix: always []
+    from elasticsearch_tpu.compile_cache import (
+        CHECKOUT_CACHE, enable_compile_cache,
+    )
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = enable_compile_cache()
+        if env_dir:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(
+                __file__)))
+            assert got == str(CHECKOUT_CACHE) == os.path.join(
+                repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_node_stats_include_probes(tmp_path):

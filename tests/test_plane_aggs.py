@@ -308,8 +308,9 @@ def test_ineligible_shapes_keep_host_path_typed():
 @pytest.mark.parametrize("seed", [71 + 1000 * k for k in range(CHAOS_SEEDS)])
 def test_cluster_parity_and_dense_device_label(seed):
     """Full path through the cluster: plane-off and plane-on responses
-    identical (hits AND aggregations), the dense_device label visible on
-    the latency-histogram surface, and NEVER in the response body."""
+    identical (hits AND aggregations) but for their ``_data_plane``,
+    which names the member's plane ("dense" on the host, "dense_device"
+    on the columns plane) as the latency histograms do."""
     from elasticsearch_tpu.testing import InProcessCluster
     c = InProcessCluster(n_nodes=1, seed=seed)
     c.start()
@@ -356,7 +357,8 @@ def test_cluster_parity_and_dense_device_label(seed):
             return resp
 
         def strip(resp):
-            return {k: v for k, v in resp.items() if k != "took"}
+            return {k: v for k, v in resp.items()
+                    if k not in ("took", "_data_plane")}
 
         def dense_obs():
             # TELEMETRY is process-global: earlier tests may already
@@ -377,7 +379,8 @@ def test_cluster_parity_and_dense_device_label(seed):
         _jeq(strip(host), strip(dev))
         assert PLANES.stats["plane_aggs_queries"] > q_off
         assert dense_obs() > obs0, dict(telemetry.TELEMETRY._planes)
-        assert "_data_plane" not in dev
+        assert host["_data_plane"] == "dense"
+        assert dev["_data_plane"] == "dense_device"
         assert telemetry.TELEMETRY.fallbacks.get("unknown", 0) == 0
     finally:
         c.stop()

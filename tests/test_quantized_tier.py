@@ -56,14 +56,17 @@ def _tier_defaults():
     MESH_PLANES.max_devices = 0
 
 
-def _engine(seed: int, n_docs: int = N_DOCS, label: str = "qt"):
+def _engine(seed: int, n_docs: int = N_DOCS, label: str = "qt",
+            vec_options: dict = None):
     rng = np.random.default_rng(seed)
     vocab = [f"w{i}" for i in range(60)]
     eng = InternalEngine(
         MapperService({"properties": {
             "body": {"type": "text"},
             "vec": {"type": "dense_vector", "dims": 8,
-                    "similarity": "cosine"},
+                    "similarity": "cosine",
+                    **({"index_options": vec_options}
+                       if vec_options else {})},
             "feats": {"type": "rank_features"},
             "tag": {"type": "keyword"}}}),
         shard_label=f"{label}{seed}")
@@ -377,6 +380,70 @@ def test_mesh_quantized_identity_vs_per_shard_fanout(seed):
                 [(c.segment_idx, c.doc, c.score) for c in fan_s[s][q][0]]
             assert mesh_s[s][q][1] == fan_s[s][q][1]
     assert MESH_PLANES.stats["mesh_quantized_mirror_builds"] >= 3
+
+
+@pytest.mark.parametrize("seed", [113 + 1000 * k for k in range(CHAOS_SEEDS)])
+def test_flat_mapped_knn_stays_exact_solo_and_mesh(seed, monkeypatch):
+    """``index_options: {type: flat}`` asks for exact answers: the
+    batched classification keeps it on the batch plane, and the plane
+    and the mesh serve it with the exact kernel — zero quantized
+    queries, ANN-sized segments included — returning the per-segment
+    exact path's hits, while the same vectors auto-mapped DO engage the
+    int8 tier (the control)."""
+    from elasticsearch_tpu.search import execute as execute_mod
+    from elasticsearch_tpu.search.batch_executor import (
+        BatchSpec, _build_ctxs, _classify,
+    )
+    from elasticsearch_tpu.search.plane_exec import (
+        mesh_knn_winners, plane_knn_winners,
+    )
+    engines = [_engine(seed + s, n_docs=900, label=f"qtf{s}",
+                       vec_options={"type": "flat"})[0] for s in range(2)]
+    auto, rng = _engine(seed, n_docs=900, label="qtfa")
+    readers = [e.acquire_reader() for e in engines]
+    qv = [float(x) for x in rng.standard_normal(8)]
+    body = {"knn": {"field": "vec", "k": 7, "query_vector": qv}}
+    assert _classify({"body": {"query": body}, "window": 10},
+                     engines[0].mappers).kind == "knn"
+
+    PLANES.enabled = False                  # the per-segment exact path
+    ref = [_run(e, r, body) for e, r in zip(engines, readers)]
+    PLANES.enabled = True
+    q0 = PLANES.stats["quantized_queries"]
+    b0 = PLANES.stats["plane_builds"]
+    for e, r, want in zip(engines, readers, ref):
+        _assert_same(want, _run(e, r, body))
+    assert PLANES.stats["plane_builds"] >= b0 + 2   # the plane served
+    assert PLANES.stats["quantized_queries"] == q0
+    _run(auto, auto.acquire_reader(), body)
+    assert PLANES.stats["quantized_queries"] > q0, "control: no engage"
+
+    # every segment is now ANN-sized: an auto field would leave the mesh
+    # for the per-segment IVF route; a flat one stays exact on the mesh
+    monkeypatch.setattr(execute_mod, "ANN_DEFAULT_MIN_DOCS", 64)
+    PLANES.min_segments = 1
+    MESH_PLANES.enabled = True
+    MESH_PLANES.min_shards = 1
+    MESH_PLANES.max_devices = 1
+    shard_segments = [(("ix", s), list(r.segments))
+                      for s, r in enumerate(readers)]
+    shard_ctxs = [_build_ctxs(r, engines[0].mappers,
+                              sum(g.n_docs for g in r.segments), None)
+                  for r in readers]
+    specs = [BatchSpec(kind="knn", field="vec", window=10,
+                       clip_limit=None, k=7, num_candidates=50,
+                       boost=1.0, query_vector=qv)]
+    mq0 = MESH_PLANES.stats["mesh_quantized_queries"]
+    mv = MESH_PLANES.get(shard_segments, "vectors", "vec")
+    mesh_k = mesh_knn_winners(shard_ctxs, mv, "vec", specs, 7)
+    assert MESH_PLANES.stats["mesh_quantized_queries"] == mq0
+    for s, r in enumerate(readers):
+        part = PLANES.get(list(r.segments), "vectors", "vec")
+        assert mesh_k[s][0] == plane_knn_winners(shard_ctxs[s], part,
+                                                 "vec", specs, 7)[0]
+        assert [(a, b) for a, b, _ in mesh_k[s][0]] == \
+            [(d.segment_idx, d.doc) for d in ref[s].docs]
+    assert PLANES.stats["quantized_queries"] == q0 + 1
 
 
 def test_mesh_mixed_knn_engagement_raises_mesh_fallback():

@@ -160,3 +160,60 @@ def test_postings_from_token_matrix_matches_builder():
         np.testing.assert_array_equal(f1, f2)
     assert pf.doc_freq[:30].tolist() == ref.doc_freq[
         [ref.terms[f"t{i}"] for i in range(30)]].tolist()
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_built_and_merged_blocks_match_plain_reference(seed):
+    """The array-built postings and feature blocks (and the merge that
+    re-packs them): every term's docs, tfs and per-doc positions, and
+    every feature's docs and weights, equal a plain dict reference."""
+    rng = np.random.default_rng(seed)
+    docs = []
+    for _ in range(400):
+        src = {}
+        if rng.random() < 0.9:
+            src["body"] = " ".join(f"w{int(x)}" for x in rng.integers(
+                0, 40, int(rng.integers(0, 30))))
+        if rng.random() < 0.7:
+            src["feats"] = {f"f{int(k)}": float(k) + 0.5 for k in
+                            rng.integers(0, 20, int(rng.integers(1, 6)))}
+        docs.append(src)
+    want_pos, want_feat = {}, {}
+    for i, src in enumerate(docs):
+        for pos, w in enumerate(src.get("body", "").split()):
+            want_pos.setdefault(w, {}).setdefault(i, []).append(pos)
+        for k, v in src.get("feats", {}).items():
+            want_feat.setdefault(k, {})[i] = v
+
+    def check(seg, order):
+        """``order``: the source doc index of each local doc."""
+        pf, ff = seg.postings["body"], seg.features["feats"]
+        for w, per in want_pos.items():
+            local = [(j, per[o]) for j, o in enumerate(order) if o in per]
+            d, tf = pf.postings_for(w)
+            assert d.tolist() == [j for j, _ in local], w
+            assert tf.tolist() == [len(p) for _, p in local], w
+            for j, p in local:
+                assert pf.positions_for(w, j).tolist() == p, (w, j)
+        for k, per in want_feat.items():
+            start, count = ff.feature_blocks(k)
+            fd = ff.block_docs[start:start + count].reshape(-1)
+            fw = ff.block_weights[start:start + count].reshape(-1)
+            assert fd[fd >= 0].tolist() == [j for j, o in enumerate(order)
+                                            if o in per], k
+            assert fw[fd >= 0].tolist() == [per[o] for o in order
+                                            if o in per], k
+
+    seg1, svc = build_segment(docs[:250], "s1")
+    b2 = SegmentBuilder("s2", svc)
+    for i, src in enumerate(docs[250:], start=250):
+        b2.add(svc.parse_document(str(i), src), seqno=i)
+    seg2 = b2.build()
+    check(seg1, list(range(250)))
+    check(seg2, list(range(250, 400)))
+    for seg, base in ((seg1, 0), (seg2, 250)):
+        for local in range(seg.n_docs):
+            if (base + local) % 5 == 0:
+                seg.delete_doc(local)
+    merged = merge_segments("m", [seg1, seg2], svc)
+    check(merged, [i for i in range(400) if i % 5])

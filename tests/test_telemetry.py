@@ -8,8 +8,8 @@ tree per shard plus the coordinator's, ``_nodes/stats`` serves the
 ``"search_latency"`` histograms, every routing decision / fallback
 carries a TYPED reason (the "unknown" bucket stays at zero), in-flight
 searches show their phase + chosen plane in ``GET /_tasks``, requests
-with a [timeout] budget are mesh-eligible, ``search.mesh.
-warmup_at_boot`` pays backend first-init at boot, and ``_cat/indices``
+with a [timeout] budget are mesh-eligible, a started node serves its
+first mesh-eligible search from the mesh, and ``_cat/indices``
 resolves every index's health in ONE master round trip.
 """
 
@@ -183,9 +183,9 @@ def test_profile_span_tree_shape(cluster):
     assert shards, "profile block lost its shard entries"
     tel = shards[0]["searches"][0]["telemetry"]
     assert tel["query_class"] == "bm25"
-    # every shard query is a batch member now (profile rides the
-    # per-member dense kind, occupancy 1 — still the batch plane)
-    assert tel["data_plane"] == "batch"
+    # every shard query is a batch member now; profile rides the
+    # per-member dense kind, which its trace names "dense"
+    assert tel["data_plane"] == "dense"
     names = [p["name"] for p in tel["phases"]]
     for phase in ("queue_wait", "rewrite", "device_dispatch", "demux"):
         assert phase in names, names
@@ -352,7 +352,7 @@ def test_mesh_plane_missing_counts_typed_reason(cluster, monkeypatch):
     monkeypatch.setattr(MESH_PLANES, "get", lambda *a, **kw: None)
     resp = _search(c, "tm", {"query": {"match": {"body": "w2"}},
                              "size": 4})
-    assert resp.get("_data_plane") is None      # served by the fan-out
+    assert resp.get("_data_plane") == "batch"      # served by the fan-out
     assert resp["hits"]["hits"] is not None
     assert TELEMETRY.fallbacks["mesh_plane_missing"] > before
     assert TELEMETRY.fallbacks.get("unknown", 0) == 0
@@ -441,31 +441,40 @@ def test_expired_deadline_hands_back_to_rpc_with_typed_reason(cluster):
 
 
 # ---------------------------------------------------------------------------
-# search.mesh.warmup_at_boot
+# the node brings the device backend up when it starts
 # ---------------------------------------------------------------------------
 
-def test_mesh_warmup_at_boot_setting(cluster, monkeypatch):
-    c = cluster
-    node = c.nodes["node0"]
-    monkeypatch.setattr("elasticsearch_tpu.parallel.mesh.mesh_ready",
-                        lambda: False)
-    monkeypatch.setattr(node, "_mesh_warmed", False, raising=False)
-    before = MESH_PLANES.stats["mesh_plane_warmups"]
-    _set(c, {"search.mesh.warmup_at_boot": True})
+def test_started_node_serves_first_mesh_search_from_the_mesh():
+    """Backend first-init belongs to ``Node.start``: a started node has
+    live devices, and its FIRST mesh-eligible search runs as the mesh
+    program — no per-shard RPC detour, no mesh fallback counted."""
+    c = InProcessCluster(n_nodes=1, seed=61)
+    c.start()
     try:
-        c.run_until(
-            lambda: MESH_PLANES.stats["mesh_plane_warmups"] > before,
-            30.0)
-        assert MESH_PLANES.stats["mesh_plane_warmups"] == before + 1
-        assert node._mesh_warmed
-        # once per process: further committed states don't re-pay init
-        _set(c, {"search.mesh.min_shards": 2})
-        assert MESH_PLANES.stats["mesh_plane_warmups"] == before + 1
-        # counted in the _nodes/stats mesh_plane section
-        assert node.local_node_stats()["mesh_plane"][
-            "mesh_plane_warmups"] == before + 1
+        node = c.nodes["node0"]
+        assert node.local_node_stats()["device"]["devices"]
+        client = c.client()
+        _ok(*c.call(lambda cb: client.create_index("fm", {
+            "settings": {"number_of_shards": 2, "number_of_replicas": 0},
+            "mappings": {"properties": {"body": {"type": "text"}}}}, cb)))
+        c.ensure_green("fm")
+        for d in range(24):
+            _ok(*c.call(lambda cb, d=d: client.index_doc(
+                "fm", f"d{d}", {"body": f"w{d % 5} w{d % 7}"}, cb)))
+        c.call(lambda cb: client.refresh("fm", cb))
+        batcher = node.search_transport.batcher
+        shard_queries = batcher.stats["queries_dispatched"]
+        mesh_falls = {k: v for k, v in TELEMETRY.fallbacks.items()
+                      if k.startswith("mesh_")}
+        resp = _search(c, "fm", {"query": {"match": {"body": "w1"}},
+                                 "size": 3})
+        assert resp["_data_plane"] == "mesh_plane"
+        assert resp["hits"]["hits"]
+        assert batcher.stats["queries_dispatched"] == shard_queries
+        assert {k: v for k, v in TELEMETRY.fallbacks.items()
+                if k.startswith("mesh_")} == mesh_falls
     finally:
-        _set(c, {"search.mesh.warmup_at_boot": None})
+        c.stop()
 
 
 # ---------------------------------------------------------------------------

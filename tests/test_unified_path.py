@@ -115,7 +115,8 @@ def _wave(c, bodies):
 
 
 def _strip(resp):
-    return {k: v for k, v in resp.items() if k != "took"}
+    # _data_plane: a coordinator-cache hit says "cached"
+    return {k: v for k, v in resp.items() if k not in ("took", "_data_plane")}
 
 
 # ---------------------------------------------------------------------------
@@ -374,15 +375,16 @@ def test_tasks_phase_fidelity_occupancy_one(cluster, monkeypatch):
         orig(members, phase, occupancy=occupancy, **kw)
     monkeypatch.setattr(batcher, "_set_phase", spy)
 
-    for body in ({"query": {"match": {"body": "w1 w2"}}},   # text kind
-                 {"query": {"match": {"body": "w1"}},       # dense kind
-                  "aggs": {"b": {"terms": {"field": "brand"}}}}):
+    for body, plane in (
+            ({"query": {"match": {"body": "w1 w2"}}}, "batch"),  # text
+            ({"query": {"match": {"body": "w1"}},                # dense
+              "aggs": {"b": {"terms": {"field": "brand"}}}}, "dense")):
         seen.clear()
         req = {"index": "ux", "shard": 0, "window": 3, "body": body}
         deferred = batcher.enqueue(req)
         member = next(m for q in batcher._queues.values() for m in q)
         assert member.task.status == {"phase": "queued",
-                                      "data_plane": "batch"}
+                                      "data_plane": plane}
         got = []
         deferred._subscribe(lambda v: got.append(("ok", v)),
                             lambda e: got.append(("err", e)))
