@@ -1,0 +1,8 @@
+"""queue_wait_ms.open: mean ``queue_wait`` span of the shard batcher
+under open-loop arrivals."""
+
+from benchmark.readers import Window, shard_key, span_mean_ms
+
+
+def read(w: Window):
+    return span_mean_ms(w, shard_key(w), "queue_wait")

@@ -1,0 +1,90 @@
+"""Segments built from arrays and installed through snapshot restore
+serve the same top-10 ids and scores as the same docs sent through
+``_bulk``, for each configuration, at 2^12 docs."""
+
+import json
+
+import numpy as np
+import pytest
+
+from benchmark import harness
+from benchmark.run import HERE, load_module
+
+DOCS = 1 << 12
+N_QUERIES = 24
+
+
+def ndjson_msmarco(data, lo, hi) -> str:
+    out = []
+    for i in range(lo, hi):
+        out.append(json.dumps({"index": {"_id": str(i)}}) + "\n"
+                   + json.dumps(data.source(i)) + "\n")
+    return "".join(out)
+
+
+def ndjson_sift(data, lo, hi) -> str:
+    return "".join(json.dumps({"index": {"_id": str(i)}}) + "\n"
+                   + json.dumps({"vec": data.vectors[i].tolist()}) + "\n"
+                   for i in range(lo, hi))
+
+
+CASES = [
+    ("msmarco-passage", ndjson_msmarco, ("bm25.serial",)),
+    ("sift1m", ndjson_sift, ("knn.open",)),
+]
+
+
+@pytest.mark.parametrize("config,ndjson,traffics", CASES,
+                         ids=[c[0] for c in CASES])
+def test_array_segments_serve_like_bulk(config, ndjson, traffics):
+    cfg = json.loads((HERE / "configs" / f"{config}.json").read_text())
+    mod = load_module(HERE / "configs" / f"{config}.py", f"cfg_{config}")
+    data = mod.Data(cfg, 2 ** 33 + 5, n_docs=DOCS)
+    srv = harness.Served()
+    try:
+        harness.create_index(srv, data.index, data.index_body())
+        harness.install_segments(srv, data.index, data.build_segments)
+        harness.create_index(srv, "bulk", data.index_body())
+        per_seg = DOCS // int(cfg["segments"])
+        for lo in range(0, DOCS, per_seg):
+            out = srv.ok("POST", "/bulk/_bulk", ndjson(data, lo, lo + per_seg),
+                         ndjson=True)
+            assert not out.get("errors")
+            srv.ok("POST", "/bulk/_refresh")
+        for name in traffics:
+            traffic = json.loads(
+                (HERE / "traffic" / f"{name}.json").read_text())
+            pool = data.pool(traffic, 11)[:N_QUERIES]
+            for spec in pool:
+                body = data.body(spec)
+                a = srv.ok("POST", f"/{data.index}/_search", body)
+                b = srv.ok("POST", "/bulk/_search", body)
+                hits_a = [(h["_id"], h["_score"]) for h in a["hits"]["hits"]]
+                hits_b = [(h["_id"], h["_score"]) for h in b["hits"]["hits"]]
+                assert hits_a, f"{name}: no hits for {spec}"
+                assert hits_a == hits_b, f"{name}: {spec}"
+    finally:
+        srv.close()
+
+
+def test_reference_agrees_with_served_scores():
+    """The numpy reference scores the served top-10 within f32
+    rounding (a check of the reference, not of the limits)."""
+    cfg = json.loads((HERE / "configs" / "msmarco-passage.json").read_text())
+    mod = load_module(HERE / "configs" / "msmarco-passage.py", "cfg_ms")
+    data = mod.Data(cfg, 3, n_docs=DOCS)
+    traffic = json.loads((HERE / "traffic" / "bm25.serial.json").read_text())
+    pool = data.pool(traffic, 4)[:N_QUERIES]
+    srv = harness.Served()
+    try:
+        harness.create_index(srv, data.index, data.index_body())
+        harness.install_segments(srv, data.index, data.build_segments)
+        resps = [srv.ok("POST", f"/{data.index}/_search", data.body(s))
+                 for s in pool]
+    finally:
+        srv.close()
+    refs = data.reference(pool)
+    got = harness.compare(resps, lambda i: refs[i], data.source, data.n_docs)
+    assert got["rank_gap"] < 1e-5 and got["score_gap"] < 1e-5, got
+    assert got["fetch_errors"] == 0 and got["lost"] == 0, got
+    assert np.isfinite(got["score_gap"])
