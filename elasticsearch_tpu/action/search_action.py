@@ -440,13 +440,15 @@ class SearchTransportService:
                 return cached
             shard.search_stats["request_cache_misses"] += 1
             cache_state = (shard_key, generation, norm_key)
-        query = dsl.parse_query(body.get("query"))
-        sort = parse_sort(body.get("sort"))
-        if trace is None:
-            trace = SearchTrace(telemetry.classify_query_class(query),
-                                "solo")
+        own_trace = trace is None
+        if own_trace:
+            trace = SearchTrace("other", "solo")
             trace.t0_ns = entry_ns
-        trace.add_span("rewrite", time.monotonic_ns() - entry_ns)
+        with telemetry.region("rewrite", trace):
+            query = dsl.parse_query(body.get("query"))
+            sort = parse_sort(body.get("sort"))
+        if own_trace:
+            trace.query_class = telemetry.classify_query_class(query)
 
         aggregator = None
         agg_body = body.get("aggs", body.get("aggregations"))
@@ -464,6 +466,7 @@ class SearchTransportService:
                 # histograms, NEVER in the response body
                 trace.data_plane = "dense_device"
 
+        syncs0, wait0 = trace.syncs, trace.wait_ns
         with telemetry.activate(trace), trace.span("device_dispatch"):
             result = query_shard(
                 reader, shard.engine.mappers, query,
@@ -481,42 +484,46 @@ class SearchTransportService:
                 profile=bool(body.get("profile")),
                 terminate_after=body.get("terminate_after"),
                 cancel_check=cancel_check)
-        t_demux = time.monotonic_ns()
-        stats = shard.search_stats
-        stats["query_total"] += 1
-        if result.collector == "wand_topk" and result.prune_stats:
-            stats["wand_queries"] += 1
-            stats["wand_blocks_total"] += result.prune_stats[0]
-            stats["wand_blocks_scored"] += result.prune_stats[1]
-        context_id = None
-        if req["window"] > 0:
-            # size=0 (count) searches never fetch: don't pin a reader
-            context_id = uuid_mod.uuid4().hex
-            self._contexts[context_id] = (reader,
-                                          self._now() + CONTEXT_KEEP_ALIVE)
-        response = {
-            "context_id": context_id,
-            "total": result.total_hits,
-            "relation": result.total_relation,
-            "max_score": result.max_score,
-            "collector": result.collector,
-            "prune": list(result.prune_stats) if result.prune_stats else None,
-            "docs": [{"segment": d.segment_idx, "doc": d.doc,
-                      "score": d.score, "sort": list(d.sort_values),
-                      **({"ckey": d.ckey} if d.ckey is not None else {})}
-                     for d in result.docs],
-            "terminated": result.terminated_early,
-            "aggs_partial": aggregator.partial() if aggregator else None,
-            "suggest_partial": (
-                _suggest_partial(reader, shard.engine.mappers, body)
-                if body.get("suggest") else None),
-            "profile": result.profile,
-        }
-        if cache_state is not None:
-            self.request_cache.put(
-                *cache_state, {**response, "context_id": None},
-                cause=lambda: shard.engine.search_generation_cause)
-        trace.add_span("demux", time.monotonic_ns() - t_demux)
+        if trace.syncs > syncs0:
+            trace.add_span("device_wait", trace.wait_ns - wait0,
+                           {"syncs": trace.syncs - syncs0})
+        with telemetry.region("demux", trace):
+            stats = shard.search_stats
+            stats["query_total"] += 1
+            if result.collector == "wand_topk" and result.prune_stats:
+                stats["wand_queries"] += 1
+                stats["wand_blocks_total"] += result.prune_stats[0]
+                stats["wand_blocks_scored"] += result.prune_stats[1]
+            context_id = None
+            if req["window"] > 0:
+                # size=0 (count) searches never fetch: don't pin a reader
+                context_id = uuid_mod.uuid4().hex
+                self._contexts[context_id] = (
+                    reader, self._now() + CONTEXT_KEEP_ALIVE)
+            response = {
+                "context_id": context_id,
+                "total": result.total_hits,
+                "relation": result.total_relation,
+                "max_score": result.max_score,
+                "collector": result.collector,
+                "prune": list(result.prune_stats)
+                if result.prune_stats else None,
+                "docs": [{"segment": d.segment_idx, "doc": d.doc,
+                          "score": d.score, "sort": list(d.sort_values),
+                          **({"ckey": d.ckey}
+                             if d.ckey is not None else {})}
+                         for d in result.docs],
+                "terminated": result.terminated_early,
+                "aggs_partial": aggregator.partial() if aggregator else None,
+                "suggest_partial": (
+                    _suggest_partial(reader, shard.engine.mappers, body)
+                    if body.get("suggest") else None),
+                "profile": result.profile,
+            }
+            if cache_state is not None:
+                self.request_cache.put(
+                    *cache_state, {**response, "context_id": None},
+                    cause=lambda: shard.engine.search_generation_cause)
         trace.finish()
         TELEMETRY.observe(trace)
         if result.profile is not None:
@@ -554,6 +561,10 @@ class SearchTransportService:
         return self.indices.shard(req["index"], req["shard"])
 
     def _on_fetch(self, req: Dict[str, Any], sender: str) -> Dict[str, Any]:
+        with telemetry.region("fetch"):
+            return self._fetch_docs(req)
+
+    def _fetch_docs(self, req: Dict[str, Any]) -> Dict[str, Any]:
         self._reap()
         # fetch is the context's last use: release it (the reference frees
         # query contexts once the fetch phase completes)
@@ -1247,96 +1258,97 @@ class TransportSearchAction:
         # can-match / query fan-out / merge / fetch), classed by body
         # shape (identical pre/post expansion rewrite), labeled by the
         # routing decision at finalize. Anchored at handler entry and
-        # built BEFORE the rewrite work so the rewrite span measures
-        # validation/resolve/alias/expansion time and the expansion's
+        # built BEFORE the rewrite work so the rewrite region measures
+        # resolve/alias/cache-probe/expansion time and the expansion's
         # device dispatch is attributed to the request
         ctrace = SearchTrace(telemetry.classify_body(body), "fanout")
         ctrace.t0_ns = entry_ns
 
-        try:
-            max_concurrent = _parse_max_concurrent(
-                body.get("max_concurrent_shard_requests"))
-            indices = self._resolve_indices(
-                index_expression, state,
-                ignore_throttled=body.get("ignore_throttled", True))
-            # filtered aliases (AliasMetadata.filter): applied PER
-            # TARGET INDEX like the reference — a shard of a filtered
-            # index sees its alias filter(s) OR'ed; shards of plain
-            # indices in the same expression stay unfiltered. An index
-            # reached BOTH through a filtered alias and by its own name
-            # stays unfiltered (the name grants full access).
-            filters_by_index: Dict[str, List[Dict[str, Any]]] = {}
-            direct = {p.strip() for p in
-                      (index_expression or "").split(",")}
-            for _alias, iname, filt in state.metadata.alias_filters(
-                    index_expression):
-                if iname in direct:
-                    continue
-                filters_by_index.setdefault(iname, []).append(filt)
-            targets = self._shard_targets(indices, state)
-            for target in targets:
-                filters = filters_by_index.get(target["index"])
-                if filters:
-                    target["alias_filter"] = filters[0] \
-                        if len(filters) == 1 else \
-                        {"bool": {"should": filters,
-                                  "minimum_should_match": 1}}
-            # coordinator fused-result cache: a duplicate co-located
-            # fan-out answers NOW — no expansion rewrite, no can-match,
-            # no shard dispatch; a miss arms the fill so THIS fan-out's
-            # clean fused response becomes the next duplicate's answer
-            fused_ctx = self._fused_cache_probe(index_expression, body,
-                                                targets, search_type)
-            if fused_ctx is not None:
-                hit = fused_ctx.pop("hit", None)
-                if hit is not None:
-                    # observable end-to-end: the histogram entry and
-                    # the response's _data_plane both say "cached"; the
-                    # rest is byte-identical to the fan-out's, modulo
-                    # took (the original's _data_plane is stripped at
-                    # fill)
-                    ctrace.data_plane = "cached"
-                    resp = {**copy.deepcopy(hit),
-                            "took": int((time.monotonic() - t0) * 1000),
-                            "_data_plane": "cached"}
-                    ctrace.add_span("request_cache_hit",
-                                    time.monotonic_ns() - entry_ns)
-                    ctrace.finish()
-                    TELEMETRY.observe(ctrace)
-                    on_done(resp, None)
-                    return
-                inner_done = on_done
+        with telemetry.region("rewrite", ctrace):
+            try:
+                max_concurrent = _parse_max_concurrent(
+                    body.get("max_concurrent_shard_requests"))
+                indices = self._resolve_indices(
+                    index_expression, state,
+                    ignore_throttled=body.get("ignore_throttled", True))
+                # filtered aliases (AliasMetadata.filter): applied PER
+                # TARGET INDEX like the reference — a shard of a filtered
+                # index sees its alias filter(s) OR'ed; shards of plain
+                # indices in the same expression stay unfiltered. An index
+                # reached BOTH through a filtered alias and by its own name
+                # stays unfiltered (the name grants full access).
+                filters_by_index: Dict[str, List[Dict[str, Any]]] = {}
+                direct = {p.strip() for p in
+                          (index_expression or "").split(",")}
+                for _alias, iname, filt in state.metadata.alias_filters(
+                        index_expression):
+                    if iname in direct:
+                        continue
+                    filters_by_index.setdefault(iname, []).append(filt)
+                targets = self._shard_targets(indices, state)
+                for target in targets:
+                    filters = filters_by_index.get(target["index"])
+                    if filters:
+                        target["alias_filter"] = filters[0] \
+                            if len(filters) == 1 else \
+                            {"bool": {"should": filters,
+                                      "minimum_should_match": 1}}
+                # coordinator fused-result cache: a duplicate co-located
+                # fan-out answers NOW — no expansion rewrite, no can-match,
+                # no shard dispatch; a miss arms the fill so THIS fan-out's
+                # clean fused response becomes the next duplicate's answer
+                fused_ctx = self._fused_cache_probe(index_expression, body,
+                                                    targets, search_type)
+                if fused_ctx is not None:
+                    hit = fused_ctx.pop("hit", None)
+                    if hit is not None:
+                        # observable end-to-end: the histogram entry and
+                        # the response's _data_plane both say "cached"; the
+                        # rest is byte-identical to the fan-out's, modulo
+                        # took (the original's _data_plane is stripped at
+                        # fill)
+                        ctrace.data_plane = "cached"
+                        resp = {**copy.deepcopy(hit),
+                                "took": int((time.monotonic() - t0) * 1000),
+                                "_data_plane": "cached"}
+                        ctrace.add_span("request_cache_hit",
+                                        time.monotonic_ns() - entry_ns)
+                        ctrace.finish()
+                        TELEMETRY.observe(ctrace)
+                        on_done(resp, None)
+                        return
+                    inner_done = on_done
 
-                def caching_done(resp, err, _ctx=fused_ctx,
-                                 _inner=inner_done):
-                    if err is None and isinstance(resp, dict):
-                        try:
-                            self._fused_cache_fill(_ctx, resp)
-                        except Exception:  # noqa: BLE001 — the fill
-                            pass           # must never fail a response
-                    _inner(resp, err)
-                on_done = caching_done
-            # coordinator-side inference rewrite: text_expansion model_text
-            # becomes tokens ONCE per request (one batched device dispatch),
-            # never per shard/segment — TextExpansionQueryBuilder.doRewrite
-            from elasticsearch_tpu.ml.text_expansion import (
-                rewrite_body_expansions,
-            )
-            with telemetry.activate(ctrace):
-                body = rewrite_body_expansions(body)
-        except SearchEngineError as e:
-            on_done(None, e)
-            return
-        if not targets:
-            on_done(self._empty_response(t0, 0), None)
-            return
+                    def caching_done(resp, err, _ctx=fused_ctx,
+                                     _inner=inner_done):
+                        if err is None and isinstance(resp, dict):
+                            try:
+                                self._fused_cache_fill(_ctx, resp)
+                            except Exception:  # noqa: BLE001 — the fill
+                                pass           # must never fail a response
+                        _inner(resp, err)
+                    on_done = caching_done
+                # coordinator-side inference rewrite: text_expansion
+                # model_text becomes tokens ONCE per request (one batched
+                # device dispatch), never per shard/segment —
+                # TextExpansionQueryBuilder.doRewrite
+                from elasticsearch_tpu.ml.text_expansion import (
+                    rewrite_body_expansions,
+                )
+                with telemetry.activate(ctrace):
+                    body = rewrite_body_expansions(body)
+            except SearchEngineError as e:
+                on_done(None, e)
+                return
+            if not targets:
+                on_done(self._empty_response(t0, 0), None)
+                return
 
-        size = int(body.get("size", 10))
-        from_ = int(body.get("from", 0))
-        window = size + from_
+            size = int(body.get("size", 10))
+            from_ = int(body.get("from", 0))
+            window = size + from_
 
         scheduler = self.ts.transport.scheduler
-        ctrace.add_span("rewrite", time.monotonic_ns() - entry_ns)
         phase_state = {
             "skipped": 0, "failed": 0,
             "failures": [],
@@ -1358,6 +1370,8 @@ class TransportSearchAction:
                                size, phase_state, on_done):
             return
 
+        # a wait span: over several shards it spans their can-match
+        # round trips (its own host work is the es.can_match region)
         t_can_match = time.monotonic_ns()
 
         def after_can_match(live_targets: List[Dict[str, Any]]) -> None:
@@ -1620,13 +1634,16 @@ class TransportSearchAction:
     def _can_match_phase(self, targets, body, phase_state, next_phase):
         query = body.get("query")
         has_terms = False
-        if query is not None:
-            try:
-                has_terms = bool(collect_query_terms(dsl.parse_query(query)))
-            except SearchEngineError:
-                has_terms = False
-        if len(targets) <= 1 or not has_terms or \
-                _must_visit_all_shards(body):
+        with telemetry.region("can_match"):
+            if query is not None:
+                try:
+                    has_terms = bool(
+                        collect_query_terms(dsl.parse_query(query)))
+                except SearchEngineError:
+                    has_terms = False
+            skip = len(targets) <= 1 or not has_terms or \
+                _must_visit_all_shards(body)
+        if skip:
             next_phase(targets)
             return
         live: List[Dict[str, Any]] = []
@@ -2352,67 +2369,67 @@ class TransportSearchAction:
         if trace is not None and phase_state.get("_t_query_ns"):
             trace.add_span("query_phase",
                            t_merge - phase_state.pop("_t_query_ns"))
-        _task_phase(phase_state, "fetch")
-        sort_specified = body.get("sort") is not None
-        total = 0
-        relation = "eq"
-        max_score: Optional[float] = None
-        entries: List[Tuple[int, Dict[str, Any]]] = []  # (target_idx, doc)
-        for i, result in enumerate(results):
-            if result is None:
-                continue
-            total += result["total"]
-            if result["relation"] == "gte":
-                relation = "gte"
-            if result.get("terminated"):
-                phase_state["terminated_early"] = True
-            if result["max_score"] is not None:
-                max_score = (result["max_score"] if max_score is None
-                             else max(max_score, result["max_score"]))
-            for doc in result["docs"]:
-                entries.append((i, doc))
-        # the coordinator re-clips the summed total at the request's
-        # threshold (SearchPhaseController's TotalHits merge): each shard
-        # counts up to the limit independently, so the raw sum can reach
-        # n_shards * limit
-        tth = body.get("track_total_hits", 10_000)
-        if tth is not True and tth is not False and tth and total > int(tth):
-            total = int(tth)
-            relation = "gte"
-
-        if sort_specified:
-            from elasticsearch_tpu.search.phase import _cmp_values
-            sort_specs = parse_sort(body.get("sort"))
-
-            def cmp(a, b):
-                for pos, spec in enumerate(sort_specs):
-                    c = _cmp_values(a[1]["sort"][pos], b[1]["sort"][pos],
-                                    spec.order == "desc")
-                    if c:
-                        return c
-                return (a[0] - b[0]) or (a[1]["doc"] - b[1]["doc"])
-            entries.sort(key=functools.cmp_to_key(cmp))
-        else:
-            entries.sort(key=lambda e: (-e[1]["score"], e[0],
-                                        e[1]["segment"], e[1]["doc"]))
-
-        if body.get("collapse"):
-            # cross-shard collapse: keep the best hit per key
-            # (SearchPhaseController merge of CollapseTopFieldDocs)
-            from elasticsearch_tpu.search.phase import collapse_marker
-            seen: set = set()
-            deduped = []
-            for e in entries:
-                marker = collapse_marker(e[1].get("ckey"))
-                if marker in seen:
+        with telemetry.region("merge", trace):
+            _task_phase(phase_state, "fetch")
+            sort_specified = body.get("sort") is not None
+            total = 0
+            relation = "eq"
+            max_score: Optional[float] = None
+            # (target_idx, doc)
+            entries: List[Tuple[int, Dict[str, Any]]] = []
+            for i, result in enumerate(results):
+                if result is None:
                     continue
-                seen.add(marker)
-                deduped.append(e)
-            entries = deduped
+                total += result["total"]
+                if result["relation"] == "gte":
+                    relation = "gte"
+                if result.get("terminated"):
+                    phase_state["terminated_early"] = True
+                if result["max_score"] is not None:
+                    max_score = (result["max_score"] if max_score is None
+                                 else max(max_score, result["max_score"]))
+                for doc in result["docs"]:
+                    entries.append((i, doc))
+            # the coordinator re-clips the summed total at the request's
+            # threshold (SearchPhaseController's TotalHits merge): each shard
+            # counts up to the limit independently, so the raw sum can reach
+            # n_shards * limit
+            tth = body.get("track_total_hits", 10_000)
+            if tth is not True and tth is not False and tth and total > int(tth):
+                total = int(tth)
+                relation = "gte"
 
-        winners = entries[from_:from_ + size]
-        if trace is not None:
-            trace.add_span("merge", time.monotonic_ns() - t_merge)
+            if sort_specified:
+                from elasticsearch_tpu.search.phase import _cmp_values
+                sort_specs = parse_sort(body.get("sort"))
+
+                def cmp(a, b):
+                    for pos, spec in enumerate(sort_specs):
+                        c = _cmp_values(a[1]["sort"][pos], b[1]["sort"][pos],
+                                        spec.order == "desc")
+                        if c:
+                            return c
+                    return (a[0] - b[0]) or (a[1]["doc"] - b[1]["doc"])
+                entries.sort(key=functools.cmp_to_key(cmp))
+            else:
+                entries.sort(key=lambda e: (-e[1]["score"], e[0],
+                                            e[1]["segment"], e[1]["doc"]))
+
+            if body.get("collapse"):
+                # cross-shard collapse: keep the best hit per key
+                # (SearchPhaseController merge of CollapseTopFieldDocs)
+                from elasticsearch_tpu.search.phase import collapse_marker
+                seen: set = set()
+                deduped = []
+                for e in entries:
+                    marker = collapse_marker(e[1].get("ckey"))
+                    if marker in seen:
+                        continue
+                    seen.add(marker)
+                    deduped.append(e)
+                entries = deduped
+
+            winners = entries[from_:from_ + size]
         if not winners:
             self._complete(self._finalize(t0, targets, body, phase_state,
                                           n_total_shards, total, relation,

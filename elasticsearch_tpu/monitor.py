@@ -16,8 +16,10 @@ state.
 
 from __future__ import annotations
 
+import gc
 import logging
 import os
+import time
 from typing import Any, Dict, List, Optional
 
 logger = logging.getLogger(__name__)
@@ -118,6 +120,61 @@ def device_stats() -> Dict[str, Any]:
                 pass
         out["devices"].append(entry)
     return out
+
+
+class GcStats:
+    """Python's cyclic collector, per generation: collections and their
+    summed pause (``_nodes/stats`` ``runtime.gc.collectors.gen<n>``, the
+    shape of the reference's ``jvm.gc.collectors``), and each collection
+    inside the profiler annotation ``es.gc`` with its generation.
+    Installed once per process through ``gc.callbacks``; collections
+    run one at a time, so one open slot suffices."""
+
+    def __init__(self):
+        self.counts = [0, 0, 0]
+        self.time_ns = [0, 0, 0]
+        self._open = None
+        self._t0 = 0
+        self._region = None
+
+    def install(self) -> None:
+        if self._region is not None:
+            return
+        from elasticsearch_tpu.search.telemetry import region
+        # the annotation resolves here, never inside a collection
+        with region("gc", generation=0):
+            pass
+        self._region = region
+        gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        gen = int(info.get("generation", 0))
+        if phase == "start":
+            self._open = self._region("gc", generation=gen)
+            self._open.__enter__()
+            self._t0 = time.monotonic_ns()
+        elif self._open is not None:
+            dur_ns = time.monotonic_ns() - self._t0
+            self._open.__exit__(None, None, None)
+            self._open = None
+            if 0 <= gen < 3:
+                self.counts[gen] += 1
+                self.time_ns[gen] += dur_ns
+
+    def snapshot(self) -> Dict[str, Any]:
+        return {"collectors": {
+            f"gen{g}": {"collection_count": self.counts[g],
+                        "collection_time_in_nanos": self.time_ns[g]}
+            for g in range(3)}}
+
+
+GC = GcStats()
+
+
+def runtime_stats() -> Dict[str, Any]:
+    """The host runtime's own pauses (the reference's ``jvm`` section
+    for this build's Python process)."""
+    return {"gc": GC.snapshot()}
 
 
 def device_plane_stats() -> Dict[str, Any]:

@@ -61,6 +61,9 @@ class Node:
                  disk_io=None):
         self.node_id = node_id
         self.scheduler = scheduler
+        # filled by the HTTP server (rest/server.py) serving this node
+        from elasticsearch_tpu.search.telemetry import TimeCounter
+        self.http_search_return = TimeCounter()
         import uuid as _uuid
         self.discovery_node = DiscoveryNode(
             node_id=node_id, name=node_id,
@@ -303,11 +306,25 @@ class Node:
                     self.search_action.response_collector.stats()
             return ars_cache["v"]
 
+        def thread_pool_stats():
+            out = self.thread_pool.stats()
+            scheduler = self.scheduler.stats()
+            if scheduler is not None:
+                # the node's single dispatch thread (transport/scheduler)
+                out["scheduler"] = scheduler
+            return out
+
         builders = {
             "indices": lambda: self.indices_service.stats(),
             "transport": lambda: dict(self.transport_service.stats),
             "breakers": BREAKERS.stats,
-            "thread_pool": self.thread_pool.stats,
+            "thread_pool": thread_pool_stats,
+            # REST hand-back of search responses: the scheduler thread's
+            # on_done until the HTTP event loop resumes (rest/server.py)
+            "http": lambda: {
+                "search_return": self.http_search_return.snapshot()},
+            # the host runtime's collector (monitor.GcStats)
+            "runtime": monitor.runtime_stats,
             "adaptive_selection": ars_stats,
             # overload control plane: adaptive queue bounds, per-tenant
             # rejections, Retry-After values, node pressure + ARS rank
@@ -349,9 +366,9 @@ class Node:
             # per-(query class x data plane) latency histograms + the
             # typed fallback-reason taxonomy (search/telemetry.py)
             "search_latency": monitor.search_latency_stats,
-            # device observatory: per-family compile/recompile counters,
-            # execute EWMAs, FLOPs estimates + plane-HBM residency
-            # timelines (search/device_profile.py + the plane registries)
+            # device observatory: per-family compile/recompile counters
+            # + plane-HBM residency timelines (search/device_profile.py +
+            # the plane registries)
             "device_profile": monitor.device_profile_stats,
             # gateway shard-state fetch counters (fetches issued, cache
             # hits, copies reported none/corrupted/stale, reconciles)
@@ -432,8 +449,9 @@ class Node:
     def start(self) -> None:
         # bootstrap checks first (BootstrapChecks.check analog): dev mode
         # warns, ESTPU_ENFORCE_BOOTSTRAP aborts startup on failure
-        from elasticsearch_tpu.monitor import run_bootstrap_checks
+        from elasticsearch_tpu.monitor import GC, run_bootstrap_checks
         run_bootstrap_checks(self.indices_service.data_path)
+        GC.install()
         # the device backend comes up here, once: a backend that cannot
         # initialise fails the node's start, never a search later
         import jax

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from elasticsearch_tpu.index.segment import PostingsField, next_pow2
 from elasticsearch_tpu.ops.device_segment import DevicePostings, gather_query_blocks
 from elasticsearch_tpu.search.device_profile import profiled_jit
-from elasticsearch_tpu.search.telemetry import record_dispatch
+from elasticsearch_tpu.search.telemetry import record_dispatch, to_host
 
 DEFAULT_K1 = 1.2
 DEFAULT_B = 0.75
@@ -839,13 +839,16 @@ def dispatch_flat(block_docs, block_tfs, doc_lens, n_docs_pad: int,
         if len(chunks) == 1:
             s, d, h = got
             if counted:
-                return s[:n_real], d[:n_real], np.asarray(h)[:n_real]
+                return s[:n_real], d[:n_real], to_host(h)[:n_real]
             return s[:n_real], d[:n_real]
         s, d, h = got
         if counted:
-            out_h.append(np.asarray(h)[:n_real])
-        out_s.append(np.asarray(s)[:n_real])
-        out_d.append(np.asarray(d)[:n_real])
+            s, d, h = to_host(s, d, h)
+            out_h.append(h[:n_real])
+        else:
+            s, d = to_host(s, d)
+        out_s.append(s[:n_real])
+        out_d.append(d[:n_real])
     s = jnp.asarray(np.concatenate(out_s))
     d = jnp.asarray(np.concatenate(out_d))
     if counted:

@@ -15,12 +15,14 @@ import asyncio
 import json
 import signal
 import sys
+import time
 from typing import Any, Dict, Optional, Tuple
 from urllib.parse import parse_qsl, urlsplit
 
 from elasticsearch_tpu.node.node import NodeClient
 from elasticsearch_tpu.rest.controller import RestController, RestRequest
 from elasticsearch_tpu.rest.routes import build_controller
+from elasticsearch_tpu.search.telemetry import region
 
 MAX_BODY = 100 * 1024 * 1024   # http.max_content_length default (100mb)
 
@@ -59,6 +61,8 @@ class HttpServer:
         self.ssl_certfile = ssl_certfile
         self.ssl_keyfile = ssl_keyfile
         self._server: Optional[asyncio.AbstractServer] = None
+        # _nodes/stats http.search_return
+        self._search_return = client.node.http_search_return
 
     async def start(self) -> None:
         ssl_ctx = None
@@ -116,6 +120,13 @@ class HttpServer:
             raise _BadRequest("request line too long")
         if not request_line:
             return None
+        # timed from the request line on: the wait for a keep-alive
+        # connection's next request is the client's, not this layer's
+        with region("http.read"):
+            return await self._read_rest(reader, request_line)
+
+    async def _read_rest(self, reader: asyncio.StreamReader,
+                         request_line: bytes) -> RestRequest:
         parts = request_line.decode("latin-1").strip().split()
         if len(parts) < 2:
             raise _BadRequest("invalid HTTP request line")
@@ -168,9 +179,11 @@ class HttpServer:
     async def _dispatch(self, request: RestRequest) -> Tuple[int, Any]:
         loop = asyncio.get_running_loop()
         future: asyncio.Future = loop.create_future()
+        done_ns = [0]
 
         def on_done(status: int, body: Any) -> None:
             # handlers complete on the node's scheduler thread
+            done_ns[0] = time.monotonic_ns()
             loop.call_soon_threadsafe(
                 lambda: future.done() or future.set_result((status, body)))
 
@@ -196,13 +209,24 @@ class HttpServer:
             self.controller.dispatch(request, on_done)
 
         self.client.node.scheduler.submit(run)
-        return await future
+        out = await future
+        if request.path.rstrip("/").endswith("/_search") and done_ns[0]:
+            # the hand-back across threads: scheduler's on_done until
+            # this coroutine runs again on the event loop
+            self._search_return.observe(time.monotonic_ns() - done_ns[0])
+        return out
 
     async def _write_response(self, writer: asyncio.StreamWriter,
                               status: int, body: Any,
                               head: bool = False,
                               request: Optional[RestRequest] = None
                               ) -> None:
+        with region("http.write"):
+            await self._write_rest(writer, status, body, head, request)
+
+    async def _write_rest(self, writer: asyncio.StreamWriter, status: int,
+                          body: Any, head: bool,
+                          request: Optional[RestRequest]) -> None:
         if isinstance(body, str):
             payload = body.encode("utf-8")
             ctype = "text/plain; charset=UTF-8"

@@ -1038,102 +1038,106 @@ class ShardQueryBatcher:
         if not live:
             return
 
-        self.stats["batches_dispatched"] += 1
-        self.stats["queries_dispatched"] += len(live)
-        self.stats["max_occupancy"] = max(self.stats["max_occupancy"],
-                                          len(live))
-        self.node_pressure.in_flight += len(live)
-        now_ns = time.monotonic_ns()
-        for m in live:
-            self.stats["wait_ms_total"] += (now - m.enqueued_at) * 1e3
-            m.trace.add_span("queue_wait", now_ns - m.enqueued_ns)
-            if m.task is not None:
-                m.task.status = {"phase": "query", "data_plane": "batch"}
+        with telemetry.region("batch.drain", occupancy=len(live)):
+            self.stats["batches_dispatched"] += 1
+            self.stats["queries_dispatched"] += len(live)
+            self.stats["max_occupancy"] = max(self.stats["max_occupancy"],
+                                              len(live))
+            self.node_pressure.in_flight += len(live)
+            now_ns = time.monotonic_ns()
+            for m in live:
+                self.stats["wait_ms_total"] += (now - m.enqueued_at) * 1e3
+                m.trace.add_span("queue_wait", now_ns - m.enqueued_ns)
+                if m.task is not None:
+                    m.task.status = {"phase": "query", "data_plane": "batch"}
 
-        # one drain = one execution: device work is shared, so every
-        # member's trace carries the SAME device_dispatch span (annotated
-        # with the drain occupancy) — that is the honest attribution of a
-        # coalesced dispatch. Dense members execute (and trace) per
-        # member inside _execute instead.
-        dense = live[0].spec.kind == "dense"
-        drain_trace = SearchTrace(
-            _CLASS_OF_KIND.get(live[0].spec.kind, "other"), "batch")
-        fell_back = False
-        try:
-            with telemetry.activate(drain_trace):
-                self._execute(key, live)
-        except _AllMembersDead:
-            pass   # every member already carries its own error
-        except Exception as e:  # noqa: BLE001 — the shared execution
-            # must never lose queries: the ONE degrade lane re-drains
-            # each surviving member as a batch of one through the same
-            # _execute (minimal breaker transient, no plane member
-            # disagreement possible at occupancy 1)
-            fell_back = True
-            from elasticsearch_tpu.search.plane_exec import PlaneFallback
-            from elasticsearch_tpu.utils.errors import CircuitBreakingError
-            TELEMETRY.count_fallback(
-                telemetry.BATCH_IVF_NPROBE_DISAGREEMENT
-                if isinstance(e, PlaneFallback) else
-                telemetry.BATCH_BREAKER_REFUSED
-                if isinstance(e, CircuitBreakingError) else
-                telemetry.BATCH_EXEC_ERROR, len(live))
-            if isinstance(e, CircuitBreakingError):
-                # HBM pressure: halve this key's effective drain cap so
-                # the next drains fit the budget; regrown by successful
-                # full drains below
-                shrunk = max(1, len(live) // 2)
-                if shrunk < (st.get("max_size") or self.max_size()):
-                    st["max_size"] = shrunk
-                    self.stats["max_size_shrinks"] += 1
-            self.stats["member_redrains"] += len(live)
-            for m in live:
-                if m.error is not None or m.result is not None:
-                    continue
-                t_re = time.monotonic_ns()
-                sub = SearchTrace(m.trace.query_class, "batch")
-                try:
-                    with telemetry.activate(sub):
-                        self._execute(key, [m])
-                except _AllMembersDead:
-                    continue   # m.error already set
-                except Exception as e2:  # noqa: BLE001 — at occupancy 1
-                    m.error = e2   # this is the query's own error
-                    continue
-                if not dense and m.result is not None:
-                    m.trace.dispatches = sub.dispatches
-                    m.trace.plane_backed = sub.plane_backed
-                    _copy_compiles(sub, m.trace)
-                    m.trace.add_span(
-                        "device_dispatch", time.monotonic_ns() - t_re,
-                        {"occupancy": 1, "redrain": 1})
-                    m.trace.finish()
-                    TELEMETRY.observe(m.trace)
-        else:
-            # successful shared drain at the full (shrunk) cap: regrow
-            # the key's max_size toward the setting — headroom proved
-            eff = st.get("max_size")
-            if eff and len(live) >= eff:
-                grown = min(self.max_size(), int(eff) * 2)
-                if grown > eff:
-                    st["max_size"] = None if grown >= self.max_size() \
-                        else grown
-                    self.stats["max_size_grows"] += 1
-        if not fell_back and not dense:
-            exec_ns = time.monotonic_ns() - now_ns
-            meta = {"occupancy": len(live)}
-            if drain_trace.dispatches:
-                meta["dispatches"] = drain_trace.dispatches
-            for m in live:
-                if m.error is not None or m.result is None:
-                    continue    # died mid-batch / delivered elsewhere
-                t = m.trace
-                t.dispatches = drain_trace.dispatches
-                t.plane_backed = drain_trace.plane_backed
-                _copy_compiles(drain_trace, t)
-                t.add_span("device_dispatch", exec_ns, dict(meta))
-                t.finish()
-                TELEMETRY.observe(t)
+            # one drain = one execution: device work is shared, so every
+            # member's trace carries the SAME device_dispatch span (annotated
+            # with the drain occupancy) — that is the honest attribution of a
+            # coalesced dispatch. Dense members execute (and trace) per
+            # member inside _execute instead.
+            dense = live[0].spec.kind == "dense"
+            drain_trace = SearchTrace(
+                _CLASS_OF_KIND.get(live[0].spec.kind, "other"), "batch")
+            fell_back = False
+            try:
+                with telemetry.activate(drain_trace):
+                    self._execute(key, live)
+            except _AllMembersDead:
+                pass   # every member already carries its own error
+            except Exception as e:  # noqa: BLE001 — the shared execution
+                # must never lose queries: the ONE degrade lane re-drains
+                # each surviving member as a batch of one through the same
+                # _execute (minimal breaker transient, no plane member
+                # disagreement possible at occupancy 1)
+                fell_back = True
+                from elasticsearch_tpu.search.plane_exec import PlaneFallback
+                from elasticsearch_tpu.utils.errors import CircuitBreakingError
+                TELEMETRY.count_fallback(
+                    telemetry.BATCH_IVF_NPROBE_DISAGREEMENT
+                    if isinstance(e, PlaneFallback) else
+                    telemetry.BATCH_BREAKER_REFUSED
+                    if isinstance(e, CircuitBreakingError) else
+                    telemetry.BATCH_EXEC_ERROR, len(live))
+                if isinstance(e, CircuitBreakingError):
+                    # HBM pressure: halve this key's effective drain cap so
+                    # the next drains fit the budget; regrown by successful
+                    # full drains below
+                    shrunk = max(1, len(live) // 2)
+                    if shrunk < (st.get("max_size") or self.max_size()):
+                        st["max_size"] = shrunk
+                        self.stats["max_size_shrinks"] += 1
+                self.stats["member_redrains"] += len(live)
+                for m in live:
+                    if m.error is not None or m.result is not None:
+                        continue
+                    t_re = time.monotonic_ns()
+                    sub = SearchTrace(m.trace.query_class, "batch")
+                    try:
+                        with telemetry.activate(sub):
+                            self._execute(key, [m])
+                    except _AllMembersDead:
+                        continue   # m.error already set
+                    except Exception as e2:  # noqa: BLE001 — at occupancy 1
+                        m.error = e2   # this is the query's own error
+                        continue
+                    if not dense and m.result is not None:
+                        m.trace.dispatches = sub.dispatches
+                        m.trace.plane_backed = sub.plane_backed
+                        _copy_compiles(sub, m.trace)
+                        m.trace.add_span(
+                            "device_dispatch", time.monotonic_ns() - t_re,
+                            {"occupancy": 1, "redrain": 1})
+                        m.trace.add_device_wait(sub)
+                        m.trace.finish()
+                        TELEMETRY.observe(m.trace)
+            else:
+                # successful shared drain at the full (shrunk) cap: regrow
+                # the key's max_size toward the setting — headroom proved
+                eff = st.get("max_size")
+                if eff and len(live) >= eff:
+                    grown = min(self.max_size(), int(eff) * 2)
+                    if grown > eff:
+                        st["max_size"] = None if grown >= self.max_size() \
+                            else grown
+                        self.stats["max_size_grows"] += 1
+            if not fell_back and not dense:
+                exec_ns = time.monotonic_ns() - now_ns
+                meta = {"occupancy": len(live)}
+                if drain_trace.dispatches:
+                    meta["dispatches"] = drain_trace.dispatches
+                for m in live:
+                    if m.error is not None or m.result is None:
+                        continue    # died mid-batch / delivered elsewhere
+                    t = m.trace
+                    t.dispatches = drain_trace.dispatches
+                    t.plane_backed = drain_trace.plane_backed
+                    _copy_compiles(drain_trace, t)
+                    t.add_span("device_dispatch", exec_ns, dict(meta))
+                    # the drain's result syncs, inside device_dispatch
+                    t.add_device_wait(drain_trace)
+                    t.finish()
+                    TELEMETRY.observe(t)
         # pressure observation + delivery: every surviving member's
         # response carries the node's self-reported pressure (queue
         # depth, in-flight, service-time EWMA) and its own shard took —

@@ -1,6 +1,6 @@
-"""Device observatory: XLA compile/recompile tracking, per-kernel cost
-attribution, and the profiled-jit wrapper every device kernel routes
-through.
+"""Device observatory: XLA compile/recompile tracking, per-request
+compile attribution, and the profiled-jit wrapper every device kernel
+routes through.
 
 PR 8 made every *request* observable; the layer that actually determines
 TPU throughput — XLA compilation and per-kernel execution — stayed
@@ -23,11 +23,11 @@ p99 cliff. This module is the measurement:
 - :class:`DeviceProfile` (process-global ``DEVICE_PROFILE``, the PLANES /
   TELEMETRY one-accelerator-per-process precedent): per kernel-family
   compile counts vs cache hits, compile wall-time (total / max), live
-  shape-bucket cardinality, a **recompile-storm detector** (a counter +
-  slow-compile log line when a family crosses a configurable
-  distinct-compile rate), a measured execute-time EWMA per
-  (family, shape bucket), and guarded ``lowered.cost_analysis()``
-  FLOPs / bytes estimates where the backend exposes them.
+  shape-bucket cardinality and a **recompile-storm detector** (a
+  counter + slow-compile log line when a family crosses a configurable
+  distinct-compile rate). A cache-hit call costs one executable-cache
+  size read on each side of the dispatch and a counter increment; the
+  shape-bucket label is built only when the call compiled.
 - Request attribution rides the PR 8 contextvar trace: jitted functions
   cannot self-report, so the host-side wrapper is the dispatch seam —
   a compile inside an active :class:`~.telemetry.SearchTrace` adds a
@@ -35,13 +35,10 @@ p99 cliff. This module is the measurement:
   flags the trace so slow logs mark first-compile requests. Profile-off
   responses stay byte-identical: nothing here ever touches a response.
 
-Timing semantics (honest by construction, documented so nobody reads
-more into them): JAX dispatch is asynchronous, and telemetry never pays
-a device sync — so the execute EWMA measures host-observed call wall
-time (dispatch + any internal syncs), and compile wall time is the
-first-call wall time for a shape bucket (trace + XLA compile dominate
-it). The bench ``--device-profile`` gate, which DOES block on results,
-is where true device-side steady-state numbers come from.
+Timing semantics: compile wall time is the first-call wall time for a
+shape bucket (trace + XLA compile dominate it). Device time is the
+profiler trace's, never this module's: JAX dispatch is asynchronous and
+the observatory never pays a device sync.
 
 Import discipline: this module imports only the stdlib and its sibling
 ``telemetry`` at load time (``jax`` lazily, at first wrap) so the ops
@@ -60,12 +57,9 @@ from elasticsearch_tpu.search import telemetry
 
 logger = logging.getLogger(__name__)
 
-# execute-time EWMA smoothing (the NodePressure / C3 alpha family)
-EWMA_ALPHA = 0.2
-
-# per-family bound on the (shape bucket -> EWMA / cost) maps: bucket
-# labels derive from call shapes, so a pathological caller must not grow
-# node memory forever; compiles themselves stay exactly counted
+# per-family bound on the shape-bucket map: bucket labels derive from
+# call shapes, so a pathological caller must not grow node memory
+# forever; compiles themselves stay exactly counted
 MAX_BUCKETS_PER_FAMILY = 256
 
 _TRACER_TYPE: Any = None
@@ -98,8 +92,7 @@ class FamilyProfile:
     """One kernel family's observatory record."""
 
     __slots__ = ("name", "compiles", "cache_hits", "compile_ns_total",
-                 "compile_ns_max", "shapes", "execute",
-                 "cost", "compile_marks", "storms")
+                 "compile_ns_max", "shapes", "compile_marks", "storms")
 
     def __init__(self, name: str):
         self.name = name
@@ -110,10 +103,6 @@ class FamilyProfile:
         # shape-bucket label -> compile count (cardinality == the live
         # compile-cache size the pow2 bucketing invariants promise)
         self.shapes: Dict[str, int] = {}
-        # shape-bucket label -> [ewma_ms, observations]
-        self.execute: Dict[str, list] = {}
-        # shape-bucket label -> {"flops": ..., "bytes_accessed": ...}
-        self.cost: Dict[str, Dict[str, float]] = {}
         # recent compile times (monotonic seconds) for the storm window
         self.compile_marks: list = []
         self.storms = 0
@@ -138,9 +127,6 @@ class DeviceProfile:
         # multi-second XLA compile mid-serving deserves a line even
         # without a storm)
         self.slow_compile_ms = 1000.0
-        # guarded lowered.cost_analysis() estimates (one extra trace per
-        # new shape bucket; off when even that is unwanted)
-        self.cost_analysis = True
 
     def configure(self, storm_threshold: Optional[int] = None,
                   storm_window_s: Optional[float] = None,
@@ -183,8 +169,7 @@ class DeviceProfile:
 
     # -- recording --------------------------------------------------------
 
-    def on_compile(self, family: str, label: str, dur_ns: int,
-                   cost: Optional[Dict[str, float]] = None) -> None:
+    def on_compile(self, family: str, label: str, dur_ns: int) -> None:
         fam = self.family(family)
         fam.compiles += 1
         fam.compile_ns_total += int(dur_ns)
@@ -192,10 +177,6 @@ class DeviceProfile:
         fam.shapes[label] = fam.shapes.get(label, 0) + 1
         while len(fam.shapes) > MAX_BUCKETS_PER_FAMILY:
             fam.shapes.pop(next(iter(fam.shapes)))
-        if cost:
-            fam.cost[label] = cost
-            while len(fam.cost) > MAX_BUCKETS_PER_FAMILY:
-                fam.cost.pop(next(iter(fam.cost)))
         # storm detection over a sliding window of compile marks
         now = time.monotonic()
         marks = fam.compile_marks
@@ -220,19 +201,6 @@ class DeviceProfile:
         # span and the first-compile flag slow logs print
         telemetry.record_compile(family, dur_ns)
 
-    def on_execute(self, family: str, label: str, dur_ns: int) -> None:
-        fam = self.family(family)
-        fam.cache_hits += 1
-        got = fam.execute.get(label)
-        ms = dur_ns / 1e6
-        if got is None:
-            fam.execute[label] = [ms, 1]
-            while len(fam.execute) > MAX_BUCKETS_PER_FAMILY:
-                fam.execute.pop(next(iter(fam.execute)))
-        else:
-            got[0] = EWMA_ALPHA * ms + (1 - EWMA_ALPHA) * got[0]
-            got[1] += 1
-
     # -- surfaces ---------------------------------------------------------
 
     def total_compiles(self) -> int:
@@ -252,15 +220,7 @@ class DeviceProfile:
                 "compile_ms_max": round(fam.compile_ns_max / 1e6, 3),
                 "shape_buckets": len(fam.shapes),
                 "recompile_storms": fam.storms,
-                "execute_ewma_ms": {
-                    label: {"ewma_ms": round(ewma, 4), "calls": count}
-                    for label, (ewma, count)
-                    in sorted(fam.execute.items())},
             }
-            if fam.cost:
-                families[name]["cost"] = {
-                    label: {k: round(v, 1) for k, v in entry.items()}
-                    for label, entry in sorted(fam.cost.items())}
         return {
             "families": families,
             "total_compiles": self.total_compiles(),
@@ -345,29 +305,6 @@ class ProfiledJit:
                 parts.append(f"{k}={_describe_dynamic(v)}")
         return "/".join(parts)
 
-    def _cost_of(self, args, kwargs) -> Optional[Dict[str, float]]:
-        """Guarded FLOPs/bytes estimate for a freshly-compiled shape:
-        one extra trace per new bucket (compiles are rare by contract),
-        None whenever the backend doesn't expose the analysis."""
-        if not DEVICE_PROFILE.cost_analysis:
-            return None
-        try:
-            analysis = self._jitted.lower(*args, **kwargs).cost_analysis()
-            if isinstance(analysis, (list, tuple)):
-                analysis = analysis[0] if analysis else None
-            if not isinstance(analysis, dict):
-                return None
-            out: Dict[str, float] = {}
-            flops = analysis.get("flops")
-            if flops is not None:
-                out["flops"] = float(flops)
-            acc = analysis.get("bytes accessed")
-            if acc is not None:
-                out["bytes_accessed"] = float(acc)
-            return out or None
-        except Exception:  # noqa: BLE001 — estimates are best-effort
-            return None
-
     def __call__(self, *args, **kwargs):
         reg = DEVICE_PROFILE
         if not reg.enabled:
@@ -378,16 +315,14 @@ class ProfiledJit:
             # inlined inside an outer traced program: the OUTER profiled
             # kernel owns the compile attribution
             return self._jitted(*args, **kwargs)
-        label = self._label(args, kwargs)
         before = self._cache_size()
         t0 = time.monotonic_ns()
         out = self._jitted(*args, **kwargs)
         dur_ns = time.monotonic_ns() - t0
         if self._cache_size() > before:
-            reg.on_compile(self.family, label, dur_ns,
-                           self._cost_of(args, kwargs))
+            reg.on_compile(self.family, self._label(args, kwargs), dur_ns)
         else:
-            reg.on_execute(self.family, label, dur_ns)
+            reg.family(self.family).cache_hits += 1
         return out
 
 
@@ -416,8 +351,7 @@ def profiled_callable(family: str, stageable: Callable,
 def merge_device_profile_sections(sections) -> Dict[str, Any]:
     """Coordinator-side fleet merge of per-node ``device_profile``
     sections (``_cluster/stats``'s section-filtered fan-out): counters
-    sum, compile-time maxima take the max, per-bucket EWMA detail stays
-    node-local (averaging EWMAs across nodes would mean nothing)."""
+    sum, compile-time maxima take the max."""
     families: Dict[str, Dict[str, Any]] = {}
     totals = {"total_compiles": 0, "total_cache_hits": 0,
               "recompile_storms": 0}

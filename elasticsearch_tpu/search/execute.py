@@ -27,7 +27,7 @@ from elasticsearch_tpu.ops import (
     Bm25Executor, DeviceFeatures, DevicePostings, DeviceVectors, KnnExecutor,
     SparseExecutor, device_live_mask,
 )
-from elasticsearch_tpu.search import dsl
+from elasticsearch_tpu.search import dsl, telemetry
 from elasticsearch_tpu.utils.errors import QueryParsingError
 from elasticsearch_tpu.mapping.mappers import NUMERIC_TYPES, RANGE_TYPES
 
@@ -1115,8 +1115,7 @@ def knn_shard_winners(ctxs: List["SegmentContext"], field: str, specs,
         ex = KnnExecutor(dev)
         k_seg = min(k, ctx.n_docs_pad)
         s, d = ex.top_k_batch(vectors[exact_idx], ctx.live, k_seg, masks)
-        s = np.asarray(s)
-        d = np.asarray(d)
+        s, d = telemetry.to_host(s, d)
         for row, qi in enumerate(exact_idx):
             for sc, doc in zip(s[row], d[row]):
                 if sc > -np.inf:

@@ -203,39 +203,40 @@ def _coarse_wand_topk(part, per_seg, has_terms, n_q: int, live,
 
     from elasticsearch_tpu.indices.breaker import BREAKERS
     for kprime, last in _adaptive_depths(k_plane, part.n_docs_pad):
-        if check_members is not None:
-            check_members()
-        if counter is not None:
-            counter.extend((1, 1))
-        telemetry.record_dispatch(2)
-        # coarse plane (f32 accumulator) + candidate plane temporaries
-        transient = 8 * part.n_docs_pad * n_q_pad
-        with BREAKERS.breaker("request").limit_scope(
-                transient, "plane_coarse_wand"):
-            cs, cand, hits = _bm25_coarse_kernel(
-                part.block_docs, tf16, idx_dev, w_dev, qid_dev, dl16,
-                favg_dev, live, seg_ids, part.n_docs_pad, n_q_pad,
-                n_segs, kprime, k1=DEFAULT_K1, b=DEFAULT_B)
-            s, d, eps = _bm25_rerank_kernel(
-                part.block_docs, part.block_tfs, idx_dev, w_dev,
-                qid_dev, part.doc_lens, favg_dev, live, cand, cs,
-                part.n_docs_pad, n_q_pad, kprime, k_plane,
-                k1=DEFAULT_K1, b=DEFAULT_B)
-        cs_h = np.asarray(cs)
-        s_h = np.asarray(s)
-        eps_h = np.asarray(eps)
-        k_last = min(k_plane, s_h.shape[1]) - 1
-        if all(_margin_ok(float(s_h[qi, k_last]),
-                          float(cs_h[qi, kprime - 1]),
-                          float(eps_h[qi]), REL_BF16)
-               for qi in range(n_q) if has_terms[qi]):
+        # one region per depth step: the trace shows how far each drain
+        # escalated
+        with telemetry.region("plane.rerank", kprime=kprime):
+            if check_members is not None:
+                check_members()
+            if counter is not None:
+                counter.extend((1, 1))
+            telemetry.record_dispatch(2)
+            # coarse plane (f32 accumulator) + candidate plane temporaries
+            transient = 8 * part.n_docs_pad * n_q_pad
+            with BREAKERS.breaker("request").limit_scope(
+                    transient, "plane_coarse_wand"):
+                cs, cand, hits = _bm25_coarse_kernel(
+                    part.block_docs, tf16, idx_dev, w_dev, qid_dev, dl16,
+                    favg_dev, live, seg_ids, part.n_docs_pad, n_q_pad,
+                    n_segs, kprime, k1=DEFAULT_K1, b=DEFAULT_B)
+                s, d, eps = _bm25_rerank_kernel(
+                    part.block_docs, part.block_tfs, idx_dev, w_dev,
+                    qid_dev, part.doc_lens, favg_dev, live, cand, cs,
+                    part.n_docs_pad, n_q_pad, kprime, k_plane,
+                    k1=DEFAULT_K1, b=DEFAULT_B)
+            cs_h, s_h, eps_h = telemetry.to_host(cs, s, eps)
+            k_last = min(k_plane, s_h.shape[1]) - 1
+            clean = all(_margin_ok(float(s_h[qi, k_last]),
+                                   float(cs_h[qi, kprime - 1]),
+                                   float(eps_h[qi]), REL_BF16)
+                        for qi in range(n_q) if has_terms[qi])
+        if clean:
             break
         if last:
             _count_plane_quantized_fallback()
             return None
 
-    hits_h = np.asarray(hits)
-    d_h = np.asarray(d)
+    hits_h, d_h = telemetry.to_host(hits, d)
     PLANES.note_quantized(kprime, sum(1 for qi in range(n_q)
                                       if has_terms[qi]))
     empty = ([], 0, "eq", None, (0, 0))
@@ -405,7 +406,7 @@ def plane_wand_topk(ctxs, part, field: str,
     else:
         s_a, d_a = got_a
         h_a = None
-    s_a_host = np.asarray(s_a)
+    s_a_host = telemetry.to_host(s_a)
 
     theta = np.full(n_q, -np.inf)
     for qi in range(n_q):
@@ -450,6 +451,13 @@ def plane_wand_topk(ctxs, part, field: str,
                 count_segments=(part.seg_ids(), len(part.segments)))
     else:
         s_b = d_b = h_b = None
+    # the results the demux reads, fetched once each (counted dispatches
+    # return their hits on the host already)
+    if any(exact_mode[qi] and has_terms[qi] for qi in range(n_q)):
+        d_a = telemetry.to_host(d_a)
+    if need_b and any(not exact_mode[qi] and has_terms[qi]
+                      for qi in range(n_q)):
+        s_b, d_b = telemetry.to_host(s_b, d_b)
 
     out: List[Tuple] = []
     for qi in range(n_q):
@@ -457,10 +465,10 @@ def plane_wand_topk(ctxs, part, field: str,
             out.append(empty)
             continue
         if exact_mode[qi]:
-            s_row, d_row = np.asarray(s_a)[qi], np.asarray(d_a)[qi]
+            s_row, d_row = s_a_host[qi], d_a[qi]
             hits_seen = int(np.asarray(h_a)[qi]) if h_a is not None else 0
         else:
-            s_row, d_row = np.asarray(s_b)[qi], np.asarray(d_b)[qi]
+            s_row, d_row = s_b[qi], d_b[qi]
             hits_seen = (int(np.asarray(h_b)[qi].sum())
                          if h_b is not None else 0)
         finite = s_row != -np.inf

@@ -28,6 +28,15 @@ slow logs) rebuilt around this build's data planes:
   sparse kernels, the IVF probe) can attribute device programs to the
   request that launched them without threading a parameter through
   every executor signature.
+- :func:`region` — THE way a host-side region of code is timed: a
+  ``jax.profiler`` annotation ``es.<name>`` (on the profiler's clock,
+  beside the device planes, at well under a microsecond when no profiler
+  session runs) plus, when a trace is given, the same duration as one of
+  its spans. :func:`to_host` is the result sync of the drain paths: it
+  fetches device results as ``np.asarray`` would and times the wait
+  (``es.device_wait``, and a ``device_wait`` span per batch member).
+  Spans that time a WAIT across threads or callbacks (``queue_wait``,
+  the coordinator's ``query_phase`` and ``fetch``) stay ``add_span``.
 
 Byte-invisibility contract: nothing in this module ever mutates a
 response. Surfaces that DO show telemetry (profile blocks, slow logs,
@@ -138,7 +147,8 @@ class SearchTrace:
     so a flat list IS the tree."""
 
     __slots__ = ("query_class", "data_plane", "spans", "dispatches",
-                 "t0_ns", "total_ns", "plane_backed", "compiles")
+                 "t0_ns", "total_ns", "plane_backed", "compiles",
+                 "syncs", "wait_ns")
 
     def __init__(self, query_class: str = "other",
                  data_plane: str = "solo"):
@@ -153,6 +163,10 @@ class SearchTrace:
         # records them through record_compile — a first-compile request
         # pays seconds of latency the phase spans alone can't explain)
         self.compiles = 0
+        # result syncs (to_host) while this trace was active, and their
+        # summed wall time
+        self.syncs = 0
+        self.wait_ns = 0
 
     # -- span recording --------------------------------------------------
 
@@ -161,20 +175,18 @@ class SearchTrace:
         # clamp at 1ns so a "did happen" phase can never read as absent
         self.spans.append((name, max(int(dur_ns), 1), meta))
 
-    @contextmanager
-    def span(self, name: str):
-        """Time a phase; device programs launched inside (counted by
-        record_dispatch through the active-trace contextvar) annotate
-        the span with their dispatch count."""
-        d0 = self.dispatches
-        t0 = time.monotonic_ns()
-        try:
-            yield self
-        finally:
-            meta = None
-            if self.dispatches > d0:
-                meta = {"dispatches": self.dispatches - d0}
-            self.add_span(name, time.monotonic_ns() - t0, meta)
+    def span(self, name: str) -> "_Region":
+        """Time a phase (``region(name, self)``); device programs
+        launched inside annotate the span with their dispatch count."""
+        return _Region(name, self, {})
+
+    def add_device_wait(self, source: "SearchTrace") -> None:
+        """The result syncs ``source`` saw (a drain's shared trace) as
+        ONE ``device_wait`` span of this trace: their summed wall time,
+        with the sync count in its meta."""
+        if source.syncs:
+            self.add_span("device_wait", source.wait_ns,
+                          {"syncs": source.syncs})
 
     def mark_plane(self) -> None:
         """A plane executor served this request: a solo request's data
@@ -261,6 +273,101 @@ def record_compile(family: str, dur_ns: int) -> None:
         t.add_span("compile", dur_ns,
                    {"family": family,
                     "compile_ms": round(dur_ns / 1e6, 3)})
+
+
+# ---------------------------------------------------------------------------
+# host spans on the profiler clock
+# ---------------------------------------------------------------------------
+
+_ANNOTATION: Any = None
+
+
+def _annotation(name: str, meta: Dict[str, Any]):
+    """A ``jax.profiler.TraceAnnotation`` (resolved at first use, so this
+    module still loads with the stdlib alone)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name, **meta)
+
+
+class _Region:
+    """The context manager :func:`region` returns."""
+
+    __slots__ = ("name", "trace", "meta", "ann", "t0", "d0")
+
+    def __init__(self, name: str, trace: Optional[SearchTrace],
+                 meta: Dict[str, Any]):
+        self.name = name
+        self.trace = trace
+        self.meta = meta
+
+    def __enter__(self) -> Optional[SearchTrace]:
+        self.ann = _annotation("es." + self.name, self.meta)
+        self.ann.__enter__()
+        self.d0 = self.trace.dispatches if self.trace is not None else 0
+        self.t0 = time.monotonic_ns()
+        return self.trace
+
+    def __exit__(self, *exc) -> None:
+        dur_ns = time.monotonic_ns() - self.t0
+        self.ann.__exit__(None, None, None)
+        trace = self.trace
+        if trace is not None:
+            meta = None
+            if trace.dispatches > self.d0:
+                meta = {"dispatches": trace.dispatches - self.d0}
+            trace.add_span(self.name, dur_ns, meta)
+
+
+def region(name: str, trace: Optional[SearchTrace] = None,
+           **meta) -> _Region:
+    """Time a region of code run on one thread: the profiler annotation
+    ``es.<name>`` (``meta`` rides as its arguments — occupancy, depth —
+    never in the name) and, when ``trace`` is given, a span ``name`` of
+    that trace with the same duration, annotated with the device
+    programs launched inside. Nothing turns it on or off: without a
+    profiler session the annotation is a no-op costing well under a
+    microsecond."""
+    return _Region(name, trace, meta)
+
+
+def to_host(*arrays):
+    """Device results as host numpy arrays — ``np.asarray`` of each,
+    blocking exactly as it does (the transfers of several arrays start
+    together) — timed as a device wait: the annotation
+    ``es.device_wait``, and on the active trace one more sync and its
+    wall time (the drain copies the sum to each member as its
+    ``device_wait`` span). One array in, one out; several, a tuple."""
+    import jax
+    import numpy as np
+    t0 = time.monotonic_ns()
+    with _annotation("es.device_wait", {}):
+        got = [np.asarray(a) for a in jax.device_get(arrays)]
+    trace = _current.get()
+    if trace is not None:
+        trace.syncs += 1
+        trace.wait_ns += time.monotonic_ns() - t0
+    return got[0] if len(got) == 1 else tuple(got)
+
+
+class TimeCounter:
+    """An event count and its summed duration: the ``count`` /
+    ``time_in_nanos`` pair of the ``_nodes/stats`` sections."""
+
+    __slots__ = ("count", "time_ns")
+
+    def __init__(self):
+        self.count = 0
+        self.time_ns = 0
+
+    def observe(self, dur_ns: int) -> None:
+        self.count += 1
+        self.time_ns += int(dur_ns)
+
+    def snapshot(self) -> Dict[str, int]:
+        return {"count": self.count, "time_in_nanos": self.time_ns}
 
 
 def mark_plane_served() -> None:

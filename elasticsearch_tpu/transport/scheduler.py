@@ -15,7 +15,9 @@ import itertools
 import random
 import threading
 import time
-from typing import Callable, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
+
+from elasticsearch_tpu.search.telemetry import region
 
 
 class Cancellable:
@@ -50,6 +52,11 @@ class Scheduler:
 
     def submit(self, fn: Callable[[], None]) -> Cancellable:
         return self.schedule(0.0, fn)
+
+    def stats(self) -> Optional[Dict[str, int]]:
+        """The dispatch thread's counters (``_nodes/stats``
+        ``thread_pool.scheduler``), where time is the wall clock's."""
+        return None
 
 
 class DeterministicScheduler(Scheduler):
@@ -126,6 +133,10 @@ class ThreadedScheduler(Scheduler):
     deterministic scheduler enforces — handlers never race each other,
     like the reference's single applier/master threads
     (cluster/service/MasterService.java:73).
+
+    Every task runs inside the profiler annotation ``es.sched.task``;
+    ``stats()`` counts completed tasks, their summed wait (start minus
+    due time) and their summed run time.
     """
 
     def __init__(self) -> None:
@@ -133,6 +144,10 @@ class ThreadedScheduler(Scheduler):
         self._queue: List[Tuple[float, int, Cancellable, Callable]] = []
         self._counter = itertools.count()
         self._closed = False
+        self._active = 0
+        self._completed = 0
+        self._wait_ns = 0
+        self._busy_ns = 0
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="scheduler-dispatch")
         self._thread.start()
@@ -162,13 +177,34 @@ class ThreadedScheduler(Scheduler):
                     self._cv.wait(timeout=timeout)
                 if self._closed:
                     return
-                _, _, handle, fn = heapq.heappop(self._queue)
-            if not handle.cancelled:
-                try:
+                due, _, handle, fn = heapq.heappop(self._queue)
+            if handle.cancelled:
+                continue
+            start_ns = time.monotonic_ns()
+            self._active = 1
+            try:
+                with region("sched.task"):
                     fn()
-                except Exception:  # noqa: BLE001 — dispatch thread must survive
-                    import traceback
-                    traceback.print_exc()
+            except Exception:  # noqa: BLE001 — dispatch thread must survive
+                import traceback
+                traceback.print_exc()
+            finally:
+                end_ns = time.monotonic_ns()
+                self._active = 0
+                self._completed += 1
+                self._wait_ns += max(start_ns - int(due * 1e9), 0)
+                self._busy_ns += end_ns - start_ns
+
+    def stats(self) -> Dict[str, int]:
+        """``queue``: tasks past their due time, not yet started."""
+        with self._cv:
+            now = self.now()
+            queue = sum(1 for due, _, h, _ in self._queue
+                        if due <= now and not h.cancelled)
+        return {"threads": 1, "active": self._active, "queue": queue,
+                "completed": self._completed,
+                "wait_time_in_nanos": self._wait_ns,
+                "busy_time_in_nanos": self._busy_ns}
 
     def close(self) -> None:
         with self._cv:

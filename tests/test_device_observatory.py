@@ -3,11 +3,12 @@
 The device-profile layer (search/device_profile.py) must route EVERY
 jit entry point under ops/ and search/ (grep-guarded, the PR 8 "unknown
 fallback reason pinned at zero" precedent), count compiles vs cache hits
-per kernel family with live shape-bucket cardinality and an execute-time
-EWMA, detect recompile storms, attribute compiles to the active request
-trace (``profile: true`` responses gain compile spans, slow logs flag
-first-compile requests) — while profile-off responses stay byte-identical
-whether the observatory records or not. Surfaces under test:
+per kernel family with live shape-bucket cardinality (the label built on
+compiles only), detect recompile storms, attribute compiles to the
+active request trace (``profile: true`` responses gain compile spans,
+slow logs flag first-compile requests) — while profile-off responses
+stay byte-identical whether the observatory records or not. Surfaces
+under test:
 ``_nodes/stats`` "device_profile" (with the plane-HBM residency
 timeline), the ``_cluster/stats`` fleet merge, and
 ``GET /_nodes/hot_spans``. The PR 10 follow-up fixes ride along: the C3
@@ -100,6 +101,10 @@ def test_compile_and_cache_hit_accounting():
     def kern(x, k: int):
         return x * 2.0 + k
 
+    labels = []
+    label_of = kern._label
+    kern._label = lambda args, kwargs: labels.append(1) or \
+        label_of(args, kwargs)
     kern(jnp.ones(8), k=3)             # compile #1
     kern(jnp.ones(8), k=3)             # cache hit
     kern(jnp.ones(8), k=3)             # cache hit
@@ -110,11 +115,10 @@ def test_compile_and_cache_hit_accounting():
     assert snap["cache_hits"] == 2
     assert snap["shape_buckets"] == 3
     assert snap["compile_ms_total"] >= snap["compile_ms_max"] > 0
-    # execute EWMA per (family, shape bucket), only for cache hits
-    ewma = snap["execute_ewma_ms"]
-    assert len(ewma) == 1
-    entry = next(iter(ewma.values()))
-    assert entry["calls"] == 2 and entry["ewma_ms"] >= 0.0
+    # the shape-bucket label is built for the compiles alone, and the
+    # snapshot carries no per-call execute timing or cost estimate
+    assert len(labels) == 3
+    assert "execute_ewma_ms" not in snap and "cost" not in snap
 
 
 def test_inlined_call_attributes_to_outer_family():
@@ -136,22 +140,6 @@ def test_inlined_call_attributes_to_outer_family():
     fams = DEVICE_PROFILE.snapshot()["families"]
     assert fams[outer_fam]["compiles"] == 1
     assert inner_fam not in fams
-
-
-def test_cost_analysis_estimates_are_guarded():
-    fam = _fresh_family("obs_cost")
-
-    @profiled_jit(fam)
-    def kern(x):
-        return x @ x.T
-
-    kern(jnp.ones((8, 8)))
-    snap = DEVICE_PROFILE.snapshot()["families"][fam]
-    # the CPU backend exposes cost_analysis; whenever present, the
-    # estimate must carry flops for a matmul
-    cost = snap.get("cost")
-    if cost:
-        assert next(iter(cost.values()))["flops"] > 0
 
 
 # ---------------------------------------------------------------------------

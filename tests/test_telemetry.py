@@ -130,7 +130,8 @@ def _set(c, settings):
 # telemetry-only key names that must NEVER appear in a profile-off
 # response on any path
 _FORBIDDEN = ('"telemetry"', '"queue_wait"', '"device_dispatch"',
-              '"query_class"', '"phases"', '"span"')
+              '"query_class"', '"phases"', '"span"', '"device_wait"',
+              '"syncs"')
 
 
 # ---------------------------------------------------------------------------
@@ -138,19 +139,26 @@ _FORBIDDEN = ('"telemetry"', '"queue_wait"', '"device_dispatch"',
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("seed", [5 + 311 * k for k in range(CHAOS_SEEDS)])
-def test_profile_off_byte_invisibility_all_planes(cluster, seed):
+def test_profile_off_byte_invisibility_all_planes(cluster, seed, tmp_path):
+    import jax
     c = cluster
     rng = np.random.default_rng(seed)
     bodies = _bodies(rng)
     # mesh (3-shard fan-out), batch (concurrent 1-shard wave), and the
     # solo/plane paths (batch disabled) — each serialized response must
     # carry zero telemetry keys and repeat byte-identically while the
-    # histograms record in between
+    # histograms record in between, and while a profiler session records
+    # the es.* host spans
     for index, plane in (("tm", "mesh"), ("ts", "batch")):
         first = _wave(c, index, bodies)
         TELEMETRY.snapshot()           # recording mustn't perturb state
         second = _wave(c, index, bodies)
-        for body, a, b in zip(bodies, first, second):
+        jax.profiler.start_trace(str(tmp_path / plane))
+        try:
+            traced = _wave(c, index, bodies)
+        finally:
+            jax.profiler.stop_trace()
+        for body, a, b in zip(bodies * 2, first * 2, second + traced):
             raw = json.dumps(a, sort_keys=True)
             for key in _FORBIDDEN:
                 assert key not in raw, (plane, body, key)
@@ -578,10 +586,10 @@ def test_histogram_percentiles_and_lifetime_history():
 @pytest.mark.slow
 @pytest.mark.parametrize("seed",
                          [5 + 311 * k for k in range(max(CHAOS_SEEDS, 5))])
-def test_profile_off_invisibility_seed_sweep(cluster, seed):
+def test_profile_off_invisibility_seed_sweep(cluster, seed, tmp_path):
     """CI-widened sweep of the byte-invisibility golden (the tier-1 run
     covers CHAOS_SEEDS seeds; this covers >= 5)."""
-    test_profile_off_byte_invisibility_all_planes(cluster, seed)
+    test_profile_off_byte_invisibility_all_planes(cluster, seed, tmp_path)
 
 
 def test_classify_body_never_raises():
