@@ -820,7 +820,7 @@ def cfg_device_profile(np, jax, jnp, result):
     from elasticsearch_tpu.index.segment import next_pow2
     from elasticsearch_tpu.ops.bm25 import (
         _bm25_coarse_kernel, _bm25_rerank_kernel, flatten_plans,
-        qb_bucket,
+        qb_bucket, rerank_ranges,
     )
     kprime = 128
     plans16 = b_ex.build_plans(text_queries[:16])
@@ -829,6 +829,9 @@ def cfg_device_profile(np, jax, jnp, result):
     bfavg = np.full(fb, float(b_dev.avgdl), np.float32)
     bidx_d, bw_d = jnp.asarray(bidx), jnp.asarray(bw)
     bqid_d, bfavg_d = jnp.asarray(bqid), jnp.asarray(bfavg)
+    branges_d = [jnp.asarray(a) for a in rerank_ranges(
+        plans16, 16, np.full(b_dev.block_docs.shape[0], float(b_dev.avgdl),
+                             np.float32))]
     tf16 = jnp.asarray(np.asarray(b_dev.block_tfs)
                        .astype(jnp.bfloat16))
     dl16 = jnp.asarray(np.asarray(b_dev.doc_lens)
@@ -840,9 +843,8 @@ def cfg_device_profile(np, jax, jnp, result):
             b_dev.block_docs, tf16, bidx_d, bw_d, bqid_d, dl16, bfavg_d,
             b_live, seg_ids, b_dev.n_docs_pad, 16, 1, kprime)
         s, _d, _eps = _bm25_rerank_kernel(
-            b_dev.block_docs, b_dev.block_tfs, bidx_d, bw_d, bqid_d,
-            b_dev.doc_lens, bfavg_d, b_live, cand, cs,
-            b_dev.n_docs_pad, 16, kprime, K)
+            b_dev.block_docs, b_dev.block_tfs, *branges_d,
+            b_dev.doc_lens, b_live, cand, cs, K)
         block(s)
 
     from elasticsearch_tpu.ops.knn import (
@@ -1506,7 +1508,7 @@ def cfg_segmented(np, jax, jnp, result):
             for terms in text_queries:
                 seg_plans = []
                 for (pos, pf, bb, _avg) in part.refs:
-                    idxs, ws = [], []
+                    idxs, ws, ranges = [], [], []
                     for t, qtf in _counts(terms).items():
                         ti = pf.term_block_idx(t)
                         if not len(ti):
@@ -1516,12 +1518,16 @@ def cfg_segmented(np, jax, jnp, result):
                         ws.append(np.full(len(ti),
                                           idf(n_docs, df) * qtf,
                                           np.float32))
+                        ranges.append((ti[0], len(ti), ws[-1][0]))
                     i = np.concatenate(idxs) if idxs else \
                         np.zeros(0, np.int32)
                     w = np.concatenate(ws) if ws else \
                         np.zeros(0, np.float32)
                     z = np.zeros(len(i))
-                    seg_plans.append(QueryPlan(i, w, z, z))
+                    r = (np.asarray([x[0] for x in ranges], np.int32),
+                         np.asarray([x[1] for x in ranges], np.int32),
+                         np.asarray([x[2] for x in ranges], np.float32))
+                    seg_plans.append(QueryPlan(i, w, z, z, r))
                 plans.append(QueryPlan.concat(
                     seg_plans, idx_offsets=[bb for _p, _f, bb, _a
                                             in part.refs]))
@@ -1555,7 +1561,7 @@ def cfg_segmented(np, jax, jnp, result):
             from elasticsearch_tpu.index.segment import next_pow2
             from elasticsearch_tpu.ops.bm25 import (
                 _bm25_coarse_kernel, _bm25_rerank_kernel, flatten_plans,
-                qb_bucket,
+                qb_bucket, rerank_ranges,
             )
             mirror = part.quantized_mirror()
             if mirror is not None:
@@ -1567,6 +1573,8 @@ def cfg_segmented(np, jax, jnp, result):
                 qfavg = part.block_avgdl[qidx].astype(np.float32)
                 qidx_d, qw_d = jnp.asarray(qidx), jnp.asarray(qw)
                 qqid_d, qfavg_d = jnp.asarray(qqid), jnp.asarray(qfavg)
+                qranges_d = [jnp.asarray(a) for a in rerank_ranges(
+                    plans, n_qp, part.block_avgdl)]
                 seg_ids_d = part.seg_ids()
 
                 def bm25_plane_q():
@@ -1576,9 +1584,8 @@ def cfg_segmented(np, jax, jnp, result):
                         part.n_docs_pad, n_qp, len(part.segments),
                         kprime)
                     s, d, _eps = _bm25_rerank_kernel(
-                        part.block_docs, part.block_tfs, qidx_d, qw_d,
-                        qqid_d, part.doc_lens, qfavg_d, plane_live,
-                        cand, cs, part.n_docs_pad, n_qp, kprime, K)
+                        part.block_docs, part.block_tfs, *qranges_d,
+                        part.doc_lens, plane_live, cand, cs, K)
                     block(s)
                     return s, d
 

@@ -183,8 +183,9 @@ def device_plane_stats() -> Dict[str, Any]:
     resident bytes per kind (the ``columns`` doc-values plane
     included), the quantized coarse tier's configured and SERVED
     re-rank depths (rerank_depth / rerank_depth_max /
-    rerank_depth_histogram, with quantized_queries, rerank_escalations,
-    quantized_exact_fallbacks and the measured-latency engage rule's
+    rerank_depth_histogram, with quantized_queries, rerank_lookups,
+    rerank_escalations, quantized_exact_fallbacks and the
+    measured-latency engage rule's
     quantized_disengaged_slow), the drain-wide aggregation counters
     (plane_aggs_queries = specs served from a device partial,
     plane_aggs_fallbacks), and how often a missing/refused plane forced

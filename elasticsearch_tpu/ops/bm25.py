@@ -144,7 +144,8 @@ def bm25_coarse_body(block_docs, block_tfs_q, flat_idx, flat_w, flat_q,
     gather/scatter shape as ``bm25_flat_body`` but over the plane's bf16
     mirrors (``block_tfs_q`` / ``doc_lens_q`` — half the HBM gather
     traffic, the scatter-bound classes' dominant cost), contributions
-    computed in bf16 and accumulated in f32.
+    computed in bf16 and accumulated in f32. It is the only pass of the
+    two tiers that reads every posting of every query term.
 
     Returns (coarse scores [n_q, kprime], candidate plane docs
     [n_q, kprime], per-segment match counts [n_q, n_segs]). The counts
@@ -152,9 +153,10 @@ def bm25_coarse_body(block_docs, block_tfs_q, flat_idx, flat_w, flat_q,
     positive wherever the f32 kernel's are (bf16 rounds positive
     products to positive values), so ``score > 0`` flags the same doc
     set — totals never depend on the re-rank. Candidate RANKING is
-    coarse; the exact re-rank (``bm25_rerank_body``) restores golden
-    scores, and the k'-th coarse score bounds what any excluded doc
-    could have scored (the adaptive-depth margin input)."""
+    coarse; the exact re-rank (``bm25_rerank_body``) looks each
+    candidate up in the query's term ranges and restores golden scores,
+    and the k'-th coarse score bounds what any excluded doc could have
+    scored (the adaptive-depth margin input)."""
     docs = block_docs[flat_idx]             # [FB, BLOCK]
     tfs = block_tfs_q[flat_idx]             # [FB, BLOCK] bf16
     valid = docs >= 0
@@ -179,15 +181,56 @@ def bm25_coarse_body(block_docs, block_tfs_q, flat_idx, flat_w, flat_q,
     return cs, cand, hits
 
 
-def bm25_rerank_body(block_docs, block_tfs, flat_idx, flat_w, flat_q,
-                     doc_lens, flat_avgdl, live, cand, coarse_s,
-                     n_docs_pad: int, n_q: int, kprime: int, k: int,
+def posting_tfs(block_docs, block_tfs, r_start, r_count, cand):
+    """tf [n_q, R, C] of each candidate ``cand`` [n_q, C] in each term
+    range of its query (``r_start`` / ``r_count`` [n_q, R]: a run of
+    plane blocks), 0 where the range holds no posting of it.
+
+    A term's blocks are contiguous and doc-sorted, every block's entry 0
+    is a posting and only the last block pads its tail with -1 — so the
+    blocks' first docs are the term's skip list, and the postings of a
+    block are sorted up to the pad. Two branch-free bisections find the
+    candidate: over the range's blocks for the last whose first doc is
+    <= it, then over that block's lanes for the last posting <= it. Their
+    step counts come from the plane's shape (log2 NB, log2 BLOCK), so
+    the program does not depend on how many postings a query reads;
+    every step gathers one int32 per (query, range, candidate)."""
+    nb, width = block_docs.shape
+    start = r_start[:, :, None]
+    count = r_count[:, :, None]
+    c = cand[:, None, :]
+    blk = jnp.zeros(jnp.broadcast_shapes(start.shape, c.shape), jnp.int32)
+    for s in reversed(range(max(nb - 1, 1).bit_length())):
+        nxt = blk + (1 << s)
+        first = block_docs[jnp.minimum(start + nxt, nb - 1), 0]
+        blk = jnp.where((nxt < count) & (first <= c), nxt, blk)
+    row = jnp.minimum(start + blk, nb - 1)
+    lane = jnp.zeros_like(blk)
+    for s in reversed(range(max(width - 1, 1).bit_length())):
+        nxt = lane + (1 << s)
+        doc = block_docs[row, jnp.minimum(nxt, width - 1)]
+        lane = jnp.where((nxt < width) & (doc >= 0) & (doc <= c), nxt,
+                         lane)
+    hit = (block_docs[row, lane] == c) & (count > 0)
+    return jnp.where(hit, block_tfs[row, lane], 0.0)
+
+
+def bm25_rerank_body(block_docs, block_tfs, r_start, r_count, r_w,
+                     r_avgdl, doc_lens, live, cand, coarse_s, k: int,
                      k1: float = DEFAULT_K1, b: float = DEFAULT_B):
-    """The EXACT tier: re-score only the coarse candidates with the f32
-    arithmetic of ``bm25_flat_body`` — same gather order, same f32
-    contribution formula, same linear scatter-add order — but scattered
-    into a compact [n_q, kprime] candidate plane instead of the dense
-    [n_q, n_docs_pad] one, so the top-k runs over k' slots.
+    """The EXACT tier: re-score only the coarse candidates, each looked
+    up in its query's term ranges (``posting_tfs``) instead of re-reading
+    every posting — work n_q·R·k' lookups, whatever the posting count.
+
+    ``r_start`` / ``r_count`` / ``r_w`` / ``r_avgdl`` [n_q, R] are the
+    query's (segment, term) ranges in the flat plan's order (segment-
+    major, then query-term order) with the plan's idf·boost and the
+    segment's effective avgdl; padded ranges have count 0. Scores are
+    bit-equal to ``bm25_flat_body``'s dense plane: each contribution is
+    its f32 expression with the same operands, and a candidate's sum
+    runs over the ranges in plan order from 0.0. A doc has postings only
+    in its own segment's ranges and ``x + 0.0 == x``, so that is the
+    order a sequential scatter-add over the flat plan adds it in.
 
     Candidates are sorted ascending by doc id first, making score-tie
     breaks agree with the dense kernel's lower-index-wins order. Returns
@@ -197,29 +240,16 @@ def bm25_rerank_body(block_docs, block_tfs, flat_idx, flat_w, flat_q,
     order = jnp.argsort(cand, axis=1)
     cand_s = jnp.take_along_axis(cand, order, axis=1)
     cs_s = jnp.take_along_axis(coarse_s, order, axis=1)
-    rows = jnp.arange(n_q, dtype=jnp.int32)[:, None]
-    slot_flat = jnp.full((n_q * n_docs_pad,), -1, jnp.int32)
-    slot_flat = slot_flat.at[
-        (rows * n_docs_pad + cand_s).reshape(-1)].set(
-        jnp.broadcast_to(jnp.arange(kprime, dtype=jnp.int32),
-                         (n_q, kprime)).reshape(-1))
-    docs = block_docs[flat_idx]
-    tfs = block_tfs[flat_idx]
-    valid = docs >= 0
-    safe = jnp.where(valid, docs, 0)
-    dl = doc_lens[safe]
-    norm = k1 * (1.0 - b + b * dl / flat_avgdl[:, None])
-    contrib = flat_w[:, None] * tfs * (k1 + 1.0) / (tfs + norm)
-    contrib = jnp.where(valid, contrib, 0.0)
-    slot = slot_flat[
-        (flat_q[:, None] * n_docs_pad + safe).reshape(-1)
-    ].reshape(safe.shape)
-    tgt = jnp.where(slot >= 0, flat_q[:, None] * kprime + slot,
-                    n_q * kprime)       # non-candidates: out of bounds
-    cscores = jnp.zeros((n_q * kprime,), jnp.float32)
-    cscores = cscores.at[tgt.reshape(-1)].add(contrib.reshape(-1),
-                                              mode="drop")
-    cscores = cscores.reshape(n_q, kprime)
+    tfs = posting_tfs(block_docs, block_tfs, r_start, r_count, cand_s)
+    dl = doc_lens[cand_s][:, None, :]
+    norm = k1 * (1.0 - b + b * dl / r_avgdl[:, :, None])
+    contrib = r_w[:, :, None] * tfs * (k1 + 1.0) / (tfs + norm)
+    contrib = jnp.where(tfs > 0.0, contrib, 0.0)
+    # an unrolled chain of adds: XLA keeps a float add chain's order,
+    # where a reduction over the range axis may re-associate it
+    cscores = jnp.zeros(cand_s.shape, jnp.float32)
+    for r in range(r_start.shape[1]):
+        cscores = cscores + contrib[:, r]
     ok = live[cand_s] & (cscores > 0.0)
     masked = jnp.where(ok, cscores, -jnp.inf)
     s, pos = jax.lax.top_k(masked, k)
@@ -242,17 +272,42 @@ def _bm25_coarse_kernel(block_docs, block_tfs_q, flat_idx, flat_w, flat_q,
                             n_docs_pad, n_q, n_segs, kprime, k1=k1, b=b)
 
 
-@profiled_jit("bm25_rerank",
-              static_argnames=("n_docs_pad", "n_q", "kprime", "k",
-                               "k1", "b"))
-def _bm25_rerank_kernel(block_docs, block_tfs, flat_idx, flat_w, flat_q,
-                        doc_lens, flat_avgdl, live, cand, coarse_s,
-                        n_docs_pad: int, n_q: int, kprime: int, k: int,
+@profiled_jit("bm25_rerank", static_argnames=("k", "k1", "b"))
+def _bm25_rerank_kernel(block_docs, block_tfs, r_start, r_count, r_w,
+                        r_avgdl, doc_lens, live, cand, coarse_s, k: int,
                         k1: float = DEFAULT_K1, b: float = DEFAULT_B):
-    return bm25_rerank_body(block_docs, block_tfs, flat_idx, flat_w,
-                            flat_q, doc_lens, flat_avgdl, live, cand,
-                            coarse_s, n_docs_pad, n_q, kprime, k,
+    return bm25_rerank_body(block_docs, block_tfs, r_start, r_count, r_w,
+                            r_avgdl, doc_lens, live, cand, coarse_s, k,
                             k1=k1, b=b)
+
+
+def range_bucket(rows) -> int:
+    """R of the exact re-rank: a pow2 bucket (minimum 8) of the most
+    term ranges a row plan holds."""
+    return next_pow2(max([len(p.ranges[0]) for p in rows] + [1]),
+                     minimum=8)
+
+
+def rerank_ranges(rows, n_q_pad: int, block_avgdl: np.ndarray,
+                  r_pad: Optional[int] = None):
+    """The exact re-rank's host arguments: (r_start, r_count, r_w,
+    r_avgdl) [n_q_pad, R] from each row plan's term ranges, with
+    ``r_avgdl`` read off ``block_avgdl`` (the per-block avgdl the flat
+    dispatch gathers, DFS override included) at each range's start. R
+    is ``r_pad`` or ``range_bucket(rows)``; padding ranges have count
+    0."""
+    if r_pad is None:
+        r_pad = range_bucket(rows)
+    start = np.zeros((n_q_pad, r_pad), np.int32)
+    count = np.zeros((n_q_pad, r_pad), np.int32)
+    w = np.zeros((n_q_pad, r_pad), np.float32)
+    avg = np.ones((n_q_pad, r_pad), np.float32)
+    for qi, p in enumerate(rows):
+        s, c, pw = p.ranges
+        n = len(s)
+        start[qi, :n], count[qi, :n], w[qi, :n] = s, c, pw
+        avg[qi, :n] = block_avgdl[s]
+    return start, count, w, avg
 
 
 @profiled_jit("bm25_flat",
@@ -357,6 +412,10 @@ def qb_bucket(n: int, minimum: int = 32) -> int:
     return b
 
 
+_NO_RANGES = (np.zeros(0, np.int32), np.zeros(0, np.int32),
+              np.zeros(0, np.float32))
+
+
 class QueryPlan:
     """Host-side per-query block plan with block-max upper bounds.
 
@@ -364,15 +423,24 @@ class QueryPlan:
     and ub = weight*(k1+1)*block_max_impact — the max BM25 contribution any
     doc in the block can receive from its term. other_ub is the sum of the
     OTHER query terms' global per-doc bounds, so ub + other_ub bounds the
-    total score of every doc in the block (the WAND invariant)."""
+    total score of every doc in the block (the WAND invariant).
 
-    __slots__ = ("idx", "w", "ub", "other_ub")
+    ``ranges`` (start [T] int32, count [T] int32, weight [T] f32) names
+    the plan's per-term block runs in plan order, which the exact
+    re-rank looks candidates up in. A plan without blocks has none;
+    ``survivors`` and ``top_by_ub`` keep only some blocks of a run, so
+    their plans carry None."""
 
-    def __init__(self, idx, w, ub, other_ub):
+    __slots__ = ("idx", "w", "ub", "other_ub", "ranges")
+
+    def __init__(self, idx, w, ub, other_ub, ranges=None):
         self.idx = np.asarray(idx, np.int32)
         self.w = np.asarray(w, np.float32)
         self.ub = np.asarray(ub, np.float64)
         self.other_ub = np.asarray(other_ub, np.float64)
+        if ranges is None and not len(self.idx):
+            ranges = _NO_RANGES
+        self.ranges = ranges
 
     @property
     def n_blocks(self) -> int:
@@ -404,18 +472,25 @@ class QueryPlan:
     def concat(plans: "list[QueryPlan]",
                idx_offsets=None) -> "QueryPlan":
         """One plan from many (the plane path: per-segment plans joined
-        with each segment's block base added to its gather indices).
-        Per-block bounds are segment-local and stay valid unchanged."""
+        with each segment's block base added to its gather indices and
+        range starts). Per-block bounds are segment-local and stay valid
+        unchanged."""
         if not plans:
             return QueryPlan([], [], [], [])
-        idx_parts = []
-        for i, p in enumerate(plans):
-            off = 0 if idx_offsets is None else int(idx_offsets[i])
-            idx_parts.append(p.idx + np.int32(off))
-        return QueryPlan(np.concatenate(idx_parts),
+        offs = [0 if idx_offsets is None else int(idx_offsets[i])
+                for i in range(len(plans))]
+        ranges = None
+        if all(p.ranges is not None for p in plans):
+            ranges = (np.concatenate([p.ranges[0] + np.int32(off)
+                                      for p, off in zip(plans, offs)]),
+                      np.concatenate([p.ranges[1] for p in plans]),
+                      np.concatenate([p.ranges[2] for p in plans]))
+        return QueryPlan(np.concatenate([p.idx + np.int32(off)
+                                         for p, off in zip(plans, offs)]),
                          np.concatenate([p.w for p in plans]),
                          np.concatenate([p.ub for p in plans]),
-                         np.concatenate([p.other_ub for p in plans]))
+                         np.concatenate([p.other_ub for p in plans]),
+                         ranges)
 
 
 # doc-space granularity of the range-partitioned WAND bound: other-term
@@ -582,8 +657,12 @@ def build_query_plan(terms_with_weights, term_blocks_fn, block_max_impact,
             o += cell_index.pair_bound(start, count, s_j, cnt_j) \
                 * (w_j * (k1 + 1.0))
         other_parts.append(o)
+    ranges = (np.asarray([t[0] for t in per_term], np.int32),
+              np.asarray([t[1] for t in per_term], np.int32),
+              np.asarray([t[2] for t in per_term], np.float32))
     return QueryPlan(np.concatenate(idx_parts), np.concatenate(w_parts),
-                     np.concatenate(ub_parts), np.concatenate(other_parts))
+                     np.concatenate(ub_parts), np.concatenate(other_parts),
+                     ranges)
 
 
 class Bm25Executor:

@@ -737,6 +737,9 @@ class PlaneRegistry:
             "plane_evictions": 0,
             "plane_miss_fallbacks": 0,
             "quantized_queries": 0,
+            # (query, term range, candidate) lookups the exact BM25
+            # re-rank issued, one per real range and candidate per depth
+            "rerank_lookups": 0,
             "rerank_escalations": 0,
             "quantized_exact_fallbacks": 0,
             "quantized_disengaged_slow": 0,

@@ -305,39 +305,37 @@ def mesh_bm25_coarse(mesh: Mesh, n_docs_pad: int, n_q: int, kprime: int,
     return fn
 
 
-def mesh_bm25_rerank(mesh: Mesh, n_docs_pad: int, n_q: int, kprime: int,
-                     k: int, n_segs: int, k1: float, b: float):
+def mesh_bm25_rerank(mesh: Mesh, k: int, k1: float, b: float):
     """Exact re-rank tier over the stacked postings planes: per-slot
-    body is ops/bm25.py ``bm25_rerank_body`` — the same f32 contribution
-    arithmetic and linear scatter order as the exact flat kernel, into
-    the compact candidate plane — so re-ranked scores are bit-compatible
-    with the per-shard quantized path AND the exact path.
+    body is ops/bm25.py ``bm25_rerank_body`` — each candidate looked up
+    in its query's term ranges of that slot's plane, the exact flat
+    kernel's f32 contribution summed in flat-plan order — so re-ranked
+    scores are bit-compatible with the per-shard quantized path AND the
+    exact path.
 
-    fn(block_docs, block_tfs [S,NB,B] f32, flat_idx, flat_w, flat_q,
-       flat_avgdl, doc_lens [S,N] f32, live [S,N], cand [S,n_q,k'],
-       coarse_s [S,n_q,k'])
+    fn(block_docs, block_tfs [S,NB,B] f32, r_start, r_count, r_w,
+       r_avgdl [S,n_q,R], doc_lens [S,N] f32, live [S,N],
+       cand [S,n_q,k'], coarse_s [S,n_q,k'])
       -> (scores [S,n_q,k], plane docs [S,n_q,k], eps [S,n_q])"""
     from elasticsearch_tpu.ops.bm25 import bm25_rerank_body
-    key = ("bm25_rerank", id(mesh), n_docs_pad, n_q, kprime, k, n_segs,
-           k1, b)
+    key = ("bm25_rerank", id(mesh), k, k1, b)
     fn = _COMPILED.get(key)
     if fn is not None:
         return fn
 
-    def one_slot(bd, bt, fi, fw, fq, fa, dl, lv, cand, cs):
-        return bm25_rerank_body(bd, bt, fi, fw, fq, dl, fa, lv, cand,
-                                cs, n_docs_pad, n_q, kprime, k,
-                                k1=k1, b=b)
+    def one_slot(bd, bt, rs, rc, rw, ra, dl, lv, cand, cs):
+        return bm25_rerank_body(bd, bt, rs, rc, rw, ra, dl, lv, cand, cs,
+                                k, k1=k1, b=b)
 
-    def local(bd, bt, fi, fw, fq, fa, dl, lv, cand, cs):
-        return jax.vmap(one_slot)(bd, bt, fi, fw, fq, fa, dl, lv, cand,
+    def local(bd, bt, rs, rc, rw, ra, dl, lv, cand, cs):
+        return jax.vmap(one_slot)(bd, bt, rs, rc, rw, ra, dl, lv, cand,
                                   cs)
 
     p3 = P("shard", None, None)
     p2 = P("shard", None)
     fn = profiled_callable("mesh_bm25_rerank", shard_map(
         local, mesh=mesh,
-        in_specs=(p3, p3, p2, p2, p2, p2, p2, p2, p3, p3),
+        in_specs=(p3, p3, p3, p3, p3, p3, p2, p2, p3, p3),
         out_specs=(p3, p3, p2), check_vma=False))
     _COMPILED[key] = fn
     return fn

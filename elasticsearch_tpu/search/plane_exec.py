@@ -163,17 +163,19 @@ def _coarse_wand_topk(part, per_seg, has_terms, n_q: int, live,
                       counter: Optional[list]) -> Optional[List[Tuple]]:
     """The quantized two-tier text path: ONE bf16 coarse dispatch over
     the FULL (unpruned) plans — no WAND host planning, no theta sync, no
-    recount — plus ONE exact f32 re-rank of the top-k' candidates, with
-    the adaptive-depth margin loop. Totals come EXACT off the coarse
-    pass's per-segment counts (both the counts-then-skip and the
-    totals-disabled contracts), so no mode needs a second counting
-    dispatch. Returns plane_wand_topk's per-member tuples, or None when
-    the exact phases must serve (corpus below the engage threshold,
-    batch too large for one dispatch, mirror refused, margin exhausted
-    — the latter two typed plane_quantized_fallback)."""
+    recount — plus ONE exact f32 re-rank that looks the top-k'
+    candidates up in each query's term ranges (``rerank_lookups``
+    counts the lookups), with the adaptive-depth margin loop. Totals
+    come EXACT off the coarse pass's per-segment counts (both the
+    counts-then-skip and the totals-disabled contracts), so no mode
+    needs a second counting dispatch. Returns plane_wand_topk's
+    per-member tuples, or None when the exact phases must serve (corpus
+    below the engage threshold, batch too large for one dispatch, mirror
+    refused, margin exhausted — the latter two typed
+    plane_quantized_fallback)."""
     from elasticsearch_tpu.ops.bm25 import (
         MAX_BATCH_CELLS, MAX_CHUNK_Q, _bm25_coarse_kernel,
-        _bm25_rerank_kernel, flatten_plans, qb_bucket,
+        _bm25_rerank_kernel, flatten_plans, qb_bucket, rerank_ranges,
     )
     depth0 = _coarse_depth0(k_plane, part.n_docs_pad)
     if part.n_docs_total <= 4 * depth0:
@@ -197,6 +199,9 @@ def _coarse_wand_topk(part, per_seg, has_terms, n_q: int, live,
     w_dev = jnp.asarray(w)
     qid_dev = jnp.asarray(qid)
     favg_dev = jnp.asarray(flat_avg)
+    ranges_dev = [jnp.asarray(a) for a in
+                  rerank_ranges(rows, n_q_pad, eff_block_avgdl)]
+    n_ranges = sum(len(p.ranges[0]) for p in rows)
     seg_ids = part.seg_ids()
     n_segs = len(part.segments)
     blocks_total = [rows[qi].n_blocks for qi in range(n_q)]
@@ -211,7 +216,8 @@ def _coarse_wand_topk(part, per_seg, has_terms, n_q: int, live,
             if counter is not None:
                 counter.extend((1, 1))
             telemetry.record_dispatch(2)
-            # coarse plane (f32 accumulator) + candidate plane temporaries
+            PLANES.stats["rerank_lookups"] += n_ranges * kprime
+            # the coarse pass's f32 score plane and its masks
             transient = 8 * part.n_docs_pad * n_q_pad
             with BREAKERS.breaker("request").limit_scope(
                     transient, "plane_coarse_wand"):
@@ -220,9 +226,8 @@ def _coarse_wand_topk(part, per_seg, has_terms, n_q: int, live,
                     favg_dev, live, seg_ids, part.n_docs_pad, n_q_pad,
                     n_segs, kprime, k1=DEFAULT_K1, b=DEFAULT_B)
                 s, d, eps = _bm25_rerank_kernel(
-                    part.block_docs, part.block_tfs, idx_dev, w_dev,
-                    qid_dev, part.doc_lens, favg_dev, live, cand, cs,
-                    part.n_docs_pad, n_q_pad, kprime, k_plane,
+                    part.block_docs, part.block_tfs, *ranges_dev,
+                    part.doc_lens, live, cand, cs, k_plane,
                     k1=DEFAULT_K1, b=DEFAULT_B)
             cs_h, s_h, eps_h = telemetry.to_host(cs, s, eps)
             k_last = min(k_plane, s_h.shape[1]) - 1
@@ -252,7 +257,8 @@ def _coarse_wand_topk(part, per_seg, has_terms, n_q: int, live,
                       for a, b, sc in zip(si, local, s_row[finite])]
         candidates.sort(key=lambda c: (-c.score, c.segment_idx, c.doc))
         max_score = max((c.score for c in candidates), default=None)
-        # every block was gathered (twice — once per tier): no pruning
+        # the coarse pass gathered every block (the re-rank only looks
+        # candidates up): no pruning
         prune = (blocks_total[qi], blocks_total[qi])
         h_row = hits_h[qi]
         if not counts_on:
@@ -1155,7 +1161,9 @@ def mesh_wand_topk(shard_ctxs, mpart, field: str,
         scores are bit-compatible with the per-shard quantized path —
         and counts come exact off the coarse pass. None = the exact mesh
         phases below serve (typed when it is a fallback)."""
-        from elasticsearch_tpu.ops.bm25 import flatten_plans, qb_bucket
+        from elasticsearch_tpu.ops.bm25 import (
+            flatten_plans, qb_bucket, range_bucket, rerank_ranges,
+        )
         from elasticsearch_tpu.parallel.mesh import (
             mesh_bm25_coarse, mesh_bm25_rerank,
         )
@@ -1181,6 +1189,21 @@ def mesh_wand_topk(shard_ctxs, mpart, field: str,
             idx[si], w[si], qid[si] = i_s, w_s, q_s
             favg[si] = avgdl_override if avgdl_override is not None \
                 else mpart.subs[si].block_avgdl[i_s]
+        # the re-rank's term ranges, [S, n_q_pad, R] with one R for all
+        # slots (the coarse gather's slot-major layout, per range)
+        r_pad = max(range_bucket(rows) for rows in rows_full if rows)
+        r_host = [np.zeros((mpart.n_slots, n_q_pad, r_pad), dt)
+                  for dt in (np.int32, np.int32, np.float32, np.float32)]
+        for si, rows in enumerate(rows_full):
+            if not rows:
+                continue
+            b_avg = mpart.subs[si].block_avgdl
+            if avgdl_override is not None:
+                b_avg = np.full_like(b_avg, avgdl_override)
+            for dst, got in zip(r_host, rerank_ranges(rows, n_q_pad,
+                                                      b_avg, r_pad)):
+                dst[si] = got
+        ranges_dev = [jnp.asarray(a) for a in r_host]
         idx_dev, w_dev = jnp.asarray(idx), jnp.asarray(w)
         qid_dev, favg_dev = jnp.asarray(qid), jnp.asarray(favg)
         live_dev = jnp.asarray(live_host)
@@ -1197,9 +1220,7 @@ def mesh_wand_topk(shard_ctxs, mpart, field: str,
             c_fn = mesh_bm25_coarse(mpart.mesh, mpart.n_docs_pad,
                                     n_q_pad, kprime, mpart.n_segs_max,
                                     DEFAULT_K1, DEFAULT_B)
-            r_fn = mesh_bm25_rerank(mpart.mesh, mpart.n_docs_pad,
-                                    n_q_pad, kprime, k_mesh,
-                                    mpart.n_segs_max, DEFAULT_K1,
+            r_fn = mesh_bm25_rerank(mpart.mesh, k_mesh, DEFAULT_K1,
                                     DEFAULT_B)
             transient = 8 * mpart.n_docs_pad * n_q_pad * mpart.n_slots
             with BREAKERS.breaker("request").limit_scope(
@@ -1211,8 +1232,8 @@ def mesh_wand_topk(shard_ctxs, mpart, field: str,
                                       idx_dev, w_dev, qid_dev, favg_dev,
                                       live_dev, mpart.seg_ids)
                 s, d, eps = r_fn(mpart.block_docs, mpart.block_tfs,
-                                 idx_dev, w_dev, qid_dev, favg_dev,
-                                 mpart.doc_lens, live_dev, cand, cs)
+                                 *ranges_dev, mpart.doc_lens, live_dev,
+                                 cand, cs)
             cs_h, s_h = np.asarray(cs), np.asarray(s)
             eps_h = np.asarray(eps)
             k_last = min(k_mesh, s_h.shape[2]) - 1

@@ -82,6 +82,26 @@ def test_bm25_flat_compiles(one_chip):
              n_docs_pad=N_DOCS, n_q=Q, k=K, counted=True)
 
 
+def test_bm25_coarse_and_rerank_compile(one_chip):
+    from elasticsearch_tpu.ops.bm25 import (
+        _bm25_coarse_kernel, _bm25_rerank_kernel,
+    )
+    fb, kprime, n_segs, r = 8192, 128, 4, 64
+    s = lambda shape, dt: _spec(shape, dt, one_chip)  # noqa: E731
+    _compile(_bm25_coarse_kernel,
+             s((NB, BLOCK), jnp.int32), s((NB, BLOCK), jnp.bfloat16),
+             s((fb,), jnp.int32), s((fb,), jnp.float32), s((fb,), jnp.int32),
+             s((N_DOCS,), jnp.bfloat16), s((fb,), jnp.float32),
+             s((N_DOCS,), jnp.bool_), s((N_DOCS,), jnp.int32),
+             n_docs_pad=N_DOCS, n_q=Q, n_segs=n_segs, kprime=kprime)
+    _compile(_bm25_rerank_kernel,
+             s((NB, BLOCK), jnp.int32), s((NB, BLOCK), jnp.float32),
+             s((Q, r), jnp.int32), s((Q, r), jnp.int32),
+             s((Q, r), jnp.float32), s((Q, r), jnp.float32),
+             s((N_DOCS,), jnp.float32), s((N_DOCS,), jnp.bool_),
+             s((Q, kprime), jnp.int32), s((Q, kprime), jnp.float32), k=K)
+
+
 @pytest.mark.parametrize("masked", [False, True], ids=["plain", "masked"])
 def test_knn_topk_batch_compiles(one_chip, masked):
     from elasticsearch_tpu.ops.knn import (
@@ -157,6 +177,26 @@ def test_mesh_bm25_flat_compiles_on_four_chips(mesh4):
         _spec((4, 1, fb), jnp.int32, pq), _spec((4, 1, fb), jnp.float32, pq),
         _spec((4, N_SHARD), jnp.bool_, p2),
         _spec((4, N_SHARD), jnp.int32, p2))
+    assert len(compiled.output_shardings[0].device_set) == 4
+
+
+def test_mesh_bm25_rerank_compiles_on_four_chips(mesh4):
+    from elasticsearch_tpu.ops.bm25 import DEFAULT_B, DEFAULT_K1
+    from elasticsearch_tpu.parallel.mesh import mesh_bm25_rerank
+    kprime, r, nb = 128, 64, NB // 4
+    fn = mesh_bm25_rerank(mesh4, K, DEFAULT_K1, DEFAULT_B)
+    sh = lambda *axes: NamedSharding(mesh4, P(*axes))  # noqa: E731
+    p3, p2 = sh("shard", None, None), sh("shard", None)
+    compiled = _compile(
+        fn,
+        _spec((4, nb, BLOCK), jnp.int32, p3),
+        _spec((4, nb, BLOCK), jnp.float32, p3),
+        _spec((4, Q, r), jnp.int32, p3), _spec((4, Q, r), jnp.int32, p3),
+        _spec((4, Q, r), jnp.float32, p3), _spec((4, Q, r), jnp.float32, p3),
+        _spec((4, N_SHARD), jnp.float32, p2),
+        _spec((4, N_SHARD), jnp.bool_, p2),
+        _spec((4, Q, kprime), jnp.int32, p3),
+        _spec((4, Q, kprime), jnp.float32, p3))
     assert len(compiled.output_shardings[0].device_set) == 4
 
 
