@@ -21,6 +21,7 @@ import argparse
 import json
 import sys
 from pathlib import Path
+from typing import Sequence
 
 ROOT = Path(__file__).resolve().parent.parent
 if str(ROOT) not in sys.path:
@@ -28,7 +29,12 @@ if str(ROOT) not in sys.path:
 
 
 def control_numbers(workload: str, seed: int, n_docs: int = 0,
-                    check: int = 0) -> dict:
+                    check: int = 0, answers: Sequence[str] = ("control",)
+                    ) -> dict:
+    """For each name in ``answers`` (a method of the configuration's
+    ``Data`` that answers in the program's place: ``control``, or a
+    planted fault), each compared number beside its limit; the data and
+    the sample are made once for all of them."""
     import numpy as np
 
     from benchmark import harness
@@ -42,31 +48,41 @@ def control_numbers(workload: str, seed: int, n_docs: int = 0,
     n = check or int(traffic["check"])
     specs = [pool[i] for i in rng.choice(len(pool), n, replace=False)]
     refs = data.reference(specs)
-    answers = []
-    for docs, scores in data.control(specs):
-        answers.append({"hits": {"hits": [
-            {"_id": str(int(d)), "_score": float(s),
-             **({"_source": data.source(int(d))}
-                if data.source(int(d)) is not None else {})}
-            for d, s in zip(docs, scores)]}})
-    got = harness.compare(answers, lambda j: refs[j], data.source,
-                          data.n_docs)
-    return {k: {"value": got[k], "limit": traffic["limits"][k]}
-            for k in traffic["limits"]}
+    got = {}
+    for name in answers:
+        out = []
+        for docs, scores in getattr(data, name)(specs):
+            out.append({"hits": {"hits": [
+                {"_id": str(int(d)), "_score": float(s),
+                 **({"_source": data.source(int(d))}
+                    if data.source(int(d)) is not None else {})}
+                for d, s in zip(docs, scores)]}})
+        numbers = harness.compare(out, lambda j: refs[j], data.source,
+                                  harness.layout(data).id_space)
+        got[name] = {k: {"value": numbers[k], "limit": traffic["limits"][k]}
+                     for k in traffic["limits"]}
+    return got
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
+    ap.add_argument("--answers", default="control",
+                    help="comma-separated methods of the configuration "
+                         "that answer in the program's place: control, or "
+                         "a planted fault such as index_wide")
     args = ap.parse_args(argv)
     import jax
     d = jax.devices()[0]
     for seed in (int(s) for s in args.seeds.split(",")):
-        out = control_numbers(args.workload, seed)
-        print(json.dumps({"workload": args.workload, "seed": seed,
-                          "device": f"{d.platform}/{d.device_kind}",
-                          "compared": out}), flush=True)
+        got = control_numbers(args.workload, seed,
+                              answers=args.answers.split(","))
+        for name, out in got.items():
+            print(json.dumps({"workload": args.workload, "seed": seed,
+                              "answers": name,
+                              "device": f"{d.platform}/{d.device_kind}",
+                              "compared": out}), flush=True)
     return 0
 
 

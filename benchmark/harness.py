@@ -3,8 +3,10 @@
 - :class:`Served`: one node booted as ``rest.server.run_single_node``
   boots it, with its HTTP server on an event-loop thread of this
   process, and a keep-alive client for set-up and stats;
-- :func:`install_segments`: array-built segments installed through the
-  snapshot-restore entry of the shard's engine;
+- :func:`install_shards`: array-built segments installed into every
+  shard through the snapshot-restore entry of the shard's engine;
+- :func:`pack_mesh_plane`: where the index has several shards, the mesh
+  plane packed and serving before the window;
 - :func:`warm_up`: every (batch size, work bucket) the cell's traffic
   can drain, sent as ``_msearch`` batches before the window;
 - :func:`compare`: the numbers that decide ``correct``, from the
@@ -18,6 +20,7 @@ import http.client
 import json
 import math
 import threading
+from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -110,11 +113,13 @@ def create_index(srv: Served, index: str, body: dict) -> None:
                   f"&timeout=60s")
 
 
-def install_segments(srv: Served, index: str, build_segments) -> List[int]:
+def install_segments(srv: Served, index: str, build_segments,
+                     shard: int = 0) -> List[int]:
     """Install the segments ``build_segments(shard_label)`` returns into
-    shard 0 of ``index`` through ``InternalEngine.restore_segments`` (the
-    snapshot-restore path), then refresh. Returns the segment sizes."""
-    engine = srv.node.indices_service.shard(index, 0).engine
+    shard ``shard`` of ``index`` through ``InternalEngine.restore_segments``
+    (the snapshot-restore path), then refresh. Returns the segment sizes
+    of the index."""
+    engine = srv.node.indices_service.shard(index, shard).engine
     segments = build_segments(engine.shard_label)
 
     def restore():
@@ -124,6 +129,64 @@ def install_segments(srv: Served, index: str, build_segments) -> List[int]:
     srv.on_scheduler(restore)
     cat = srv.ok("GET", "/_cat/segments?format=json")
     return [int(r["docs.count"]) for r in cat if r["index"] == index]
+
+
+@dataclass(frozen=True)
+class Layout:
+    """How a configuration lays its index out, with one-shard defaults: a
+    configuration of several shards sets ``n_shards``, offers
+    ``build_shard(shard, shard_label)`` and names its docs in an
+    ``id_space`` of its own (routed ``_id``s); ``warm_class`` gives the
+    class of a query that a drain's shapes also follow."""
+    n_shards: int
+    id_space: int
+    warm_class: Optional[Callable[[dict], int]]
+
+
+def layout(data) -> Layout:
+    return Layout(int(getattr(data, "n_shards", 1)),
+                  int(getattr(data, "id_space", data.n_docs)),
+                  getattr(data, "warm_class", None))
+
+
+def install_shards(srv: Served, index: str, data) -> List[int]:
+    """Install every shard of ``index``: one shard through
+    ``data.build_segments(shard_label)``, several through
+    ``data.build_shard(shard, shard_label)``."""
+    n_shards = layout(data).n_shards
+    if n_shards == 1:
+        return install_segments(srv, index, data.build_segments)
+    sizes: List[int] = []
+    for shard in range(n_shards):
+        sizes = install_segments(
+            srv, index, lambda label, s=shard: data.build_shard(s, label),
+            shard)
+    return sizes
+
+
+def mesh_counters(stats: dict) -> Dict[str, int]:
+    """The mesh executor's and mesh planes' counters of one
+    ``_nodes/stats`` snapshot (``mesh_plane``)."""
+    m = stats.get("mesh_plane") or {}
+    return {k: int(m.get(k) or 0) for k in
+            ("mesh_searches", "mesh_batches", "mesh_fallbacks",
+             "mesh_planes_resident")}
+
+
+def pack_mesh_plane(srv: Served, index: str, body: str) -> dict:
+    """Send one search, which packs the mesh plane on the node before it
+    is answered, and check that a plane is resident and served it: the
+    packing then counts in set-up, not in the window. Returns the
+    ``mesh_plane`` section."""
+    before = mesh_counters(srv.stats())
+    srv.ok("POST", f"/{index}/_search", body)
+    stats = srv.stats()
+    now = mesh_counters(stats)
+    if now["mesh_planes_resident"] == 0 or \
+            now["mesh_searches"] == before["mesh_searches"]:
+        raise RuntimeError(f"the mesh did not serve the index: "
+                           f"{str(stats.get('mesh_plane'))[:500]}")
+    return stats["mesh_plane"]
 
 
 def new_segment(name: str, lo: int, hi: int, sources: list):
@@ -144,7 +207,9 @@ def new_segment(name: str, lo: int, hi: int, sources: list):
 
 
 def warm_ladder(keys: Sequence[float], sizes: Sequence[int],
-                per_octave: int = 2, trials: int = 4000) -> List[List[int]]:
+                per_octave: int = 2, trials: int = 4000,
+                classes: Optional[Sequence[int]] = None
+                ) -> List[List[int]]:
     """Pool indices of the warm-up batches.
 
     A drain pads its query count to a power of two and its summed work
@@ -154,8 +219,15 @@ def warm_ladder(keys: Sequence[float], sizes: Sequence[int],
     of summed work (``per_octave`` to an octave) such drains reach; for
     each level one batch of distinct queries whose keys sum to about it
     is sent. Every (size, bucket) pair the traffic can drain is then met
-    before the window."""
+    before the window.
+
+    ``classes``, where a drain's shapes also follow the largest of its
+    queries' classes (the term ranges a query's rows hold), makes each
+    level one per (class, level) pair the random drains reach, built
+    from queries of that class or below with one of that class."""
     w = np.asarray(keys, np.float64)
+    cls = np.zeros(len(w), np.int64) if classes is None else \
+        np.asarray(classes, np.int64)
     order = np.argsort(w, kind="stable")
     ws = w[order]
     rng = np.random.default_rng(0)
@@ -164,15 +236,25 @@ def warm_ladder(keys: Sequence[float], sizes: Sequence[int],
         levels = set()
         for m in sorted({b // 2 + 1, b}):
             m = min(m, len(w))
-            sums = w[rng.integers(0, len(w), (trials, m))].sum(axis=1)
-            sums = np.concatenate([sums, [ws[:m].sum(), ws[-m:].sum()]])
-            levels |= {(m, int(v)) for v in
-                       np.floor(per_octave * np.log2(np.maximum(sums, 1)))}
-        for m, level in sorted(levels):
+            picks = rng.integers(0, len(w), (trials, m))
+            sums = np.concatenate([w[picks].sum(axis=1),
+                                   [ws[:m].sum(), ws[-m:].sum()]])
+            tops = np.concatenate([cls[picks].max(axis=1),
+                                   [cls[order[:m]].max(),
+                                    cls[order[-m:]].max()]])
+            levels |= {(m, int(c), int(v)) for c, v in zip(
+                tops, np.floor(per_octave * np.log2(np.maximum(sums, 1))))}
+        for m, c, level in sorted(levels):
+            sub = order[cls[order] <= c]
             each = 2.0 ** ((level + 0.5) / per_octave) / m
-            at = int(np.clip(np.searchsorted(ws, each) - m // 2, 0,
-                             len(w) - m))
-            out.append([int(i) for i in order[at: at + m]])
+            at = int(np.clip(np.searchsorted(w[sub], each) - m // 2, 0,
+                             len(sub) - m))
+            batch = [int(i) for i in sub[at: at + m]]
+            if cls[batch].max() < c:
+                top = np.flatnonzero(cls == c)
+                batch[m // 2] = int(top[np.argmin(
+                    np.abs(w[top] - w[batch[m // 2]]))])
+            out.append(batch)
     return out
 
 

@@ -22,7 +22,8 @@ and the answers' bodies.
 ``--sweep RATES`` (a plan key ``sweep`` when driven by ``run.py``) runs
 the open loop at each rate in turn for the window's length and reports
 offered and completed rates, the backlog left at the close and the tails:
-the one-time search for the knee.
+the one-time search for the knee. It stops after the first rate that
+leaves more than half of its requests unanswered at the close.
 """
 
 from __future__ import annotations
@@ -214,7 +215,10 @@ async def main_async(plan: dict) -> None:
             records, _ = await run_open(
                 client, bodies,
                 open_schedule(rate, seconds, seed, len(bodies)), False)
-            say({"rate": rate, **summarize(records, seconds)})
+            got = summarize(records, seconds)
+            say({"rate": rate, **got})
+            if got["backlog"] > len(records) / 2:
+                break               # saturated: higher rates add nothing
         say("closed")
         say({"records": [], "answers": []})
         return

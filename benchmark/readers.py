@@ -39,6 +39,15 @@ def coordinator_key(w: Window) -> str:
     return f"{w.query_class}|fanout"
 
 
+def mesh_key(w: Window) -> str:
+    return f"{w.query_class}|mesh"
+
+
+def mesh_delta(w: Window, name: str) -> int:
+    return harness.mesh_counters(w.stats_after)[name] - \
+        harness.mesh_counters(w.stats_before)[name]
+
+
 def span_mean_ms(w: Window, key: str, span: str) -> Optional[float]:
     n, total_ms = harness.span_delta(w.stats_before, w.stats_after, key,
                                      span)
@@ -68,11 +77,12 @@ def idle_pct(w: Window) -> Optional[float]:
                     / w.window_ns)
 
 
-def roofline_pct(w: Window, family) -> Optional[float]:
+def roofline_pct(w: Window, family, chips: int = 1) -> Optional[float]:
     """100 x least time / device time of ``family``'s modules: the least
     time is the larger of its operations over the peak FLOP/s and its
     bytes over the peak bytes/s, for the work ``family.work`` says the
-    window's queries need."""
+    window's queries need, spread over ``chips`` chips; the device time
+    is the mean over the traced chips."""
     if w.trace is None:
         return None
     t_ns = trace_reduce.family_ns(w.trace, family.PREFIXES)
@@ -80,5 +90,5 @@ def roofline_pct(w: Window, family) -> Optional[float]:
         return None
     flops, nbytes = family.work(w)
     least_s = max(flops / w.peaks["flops_per_s"],
-                  nbytes / w.peaks["hbm_bytes_per_s"])
+                  nbytes / w.peaks["hbm_bytes_per_s"]) / chips
     return 100.0 * least_s / (t_ns / 1e9)

@@ -6,15 +6,16 @@
 
 Set-up (all of it in ``setup_s``, from process start to the window's
 open): create the cell's index over REST; generate the configuration's
-data from the seed; build its segments from arrays and install them
-through the engine's snapshot-restore entry; warm every batch size and
-work bucket of the traffic's pool through ``_msearch``; start the load
-generator (``loadgen.py``, a process of its own without JAX). Then the
-window: ``--seconds`` of the traffic mix over HTTP to the node in this
-process. After it: compare the window's answers with the configuration's
-plain numpy reference, and print one JSON line: the end-to-end metrics
-with ``--trace 0``, the per-layer metrics (under a profiler trace of the
-window) with ``--trace 1``.
+data from the seed; build each shard's segments from arrays and install
+them through the engine's snapshot-restore entry; where the index has
+several shards, pack the mesh plane with one search; warm every batch
+size and work bucket of the traffic's pool through ``_msearch``; start
+the load generator (``loadgen.py``, a process of its own without JAX).
+Then the window: ``--seconds`` of the traffic mix over HTTP to the node
+in this process. After it: compare the window's answers with the
+configuration's plain numpy reference, and print one JSON line: the
+end-to-end metrics with ``--trace 0``, the per-layer metrics (under a
+profiler trace of the window) with ``--trace 1``.
 
 Exits non-zero, printing no result, where JAX finds no TPU or fewer
 chips than the cell asks for, or where the program is not beside it.
@@ -159,15 +160,23 @@ def main(argv=None, *, require_tpu: bool = True, n_docs: int = 0) -> int:
     try:
         harness.create_index(srv, data.index, data.index_body())
         t0 = time.perf_counter()
-        sizes = harness.install_segments(srv, data.index,
-                                         data.build_segments)
+        lay = harness.layout(data)
+        sizes = harness.install_shards(srv, data.index, data)
         log(f"setup: segments {sizes} built and restored in "
             f"{time.perf_counter() - t0:.1f}s")
         pool = data.pool(traffic, args.seed)
         bodies = [json.dumps(data.body(s)) for s in pool]
+        if lay.n_shards > 1:
+            t0 = time.perf_counter()
+            mesh = harness.pack_mesh_plane(srv, data.index, bodies[0])
+            log(f"setup: mesh plane packed, "
+                f"{mesh.get('resident_bytes_per_device')} bytes a device, "
+                f"in {time.perf_counter() - t0:.1f}s")
         t0 = time.perf_counter()
-        batches = harness.warm_ladder([data.blocks(s) for s in pool],
-                                      traffic["warm_batches"])
+        batches = harness.warm_ladder(
+            [data.blocks(s) for s in pool], traffic["warm_batches"],
+            classes=None if lay.warm_class is None else
+            [lay.warm_class(s) for s in pool])
         warmed = harness.warm_up(srv, data.index, bodies, batches)
         log(f"setup: warm-up {len(batches)} batches, {warmed} queries in "
             f"{time.perf_counter() - t0:.1f}s")
@@ -197,11 +206,27 @@ def main(argv=None, *, require_tpu: bool = True, n_docs: int = 0) -> int:
             jax.profiler.stop_trace()
         after = srv.stats()
         out = json.loads(gen.stdout.readline())
+        if lay.n_shards > 1:
+            m0, m1 = harness.mesh_counters(before), \
+                harness.mesh_counters(after)
+            log("window: mesh " + ", ".join(
+                f"{k} +{m1[k] - m0[k]}" for k in m1) +
+                f", max_occupancy {after['mesh_plane'].get('max_occupancy')}")
+        p0, p1 = (s.get("device_plane") or {} for s in (before, after))
+        c0, c1 = (int((s.get("device_profile") or {}).get(
+            "total_compiles") or 0) for s in (before, after))
+        log("window: plane " + ", ".join(
+            f"{k} +{int(p1.get(k) or 0) - int(p0.get(k) or 0)}"
+            for k in ("rerank_escalations", "quantized_exact_fallbacks")) +
+            f", depths {p1.get('rerank_depth_histogram')}, compiles "
+            f"+{c1 - c0}")
         gen.wait(60)
         if args.sweep:
             return 0
-        peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-                   for d in devices[: int(cell["chips"])])
+        peaks_each = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                      for d in devices[: int(cell["chips"])]]
+        peak = max(peaks_each)
+        log(f"device: peak bytes in use by chip {peaks_each}")
     finally:
         if gen is not None and gen.poll() is None:
             gen.kill()
@@ -228,7 +253,8 @@ def main(argv=None, *, require_tpu: bool = True, n_docs: int = 0) -> int:
                                    replace=False).tolist())
     refs = data.reference([pool[records[i][0]] for i in sample])
     numbers = harness.compare([parsed[i] for i in sample],
-                              lambda j: refs[j], data.source, data.n_docs)
+                              lambda j: refs[j], data.source,
+                              lay.id_space)
     limits = traffic["limits"]
     correct = all(numbers[k] <= limits[k] for k in limits)
     log(f"check: {len(sample)} answers against the reference in "

@@ -1,6 +1,7 @@
 """Segments built from arrays and installed through snapshot restore
 serve the same top-10 ids and scores as the same docs sent through
-``_bulk``, for each configuration, at 2^12 docs."""
+``_bulk``, for each configuration, at 2^12 docs; a four-shard index's
+docs lie in the shards ``_bulk`` routes their ``_id`` to."""
 
 import json
 
@@ -28,9 +29,17 @@ def ndjson_sift(data, lo, hi) -> str:
                    for i in range(lo, hi))
 
 
+def ndjson_msmarco_4shard(data, lo, hi) -> str:
+    """Generator docs [lo, hi) under their routed ``_id``s."""
+    return "".join(json.dumps({"index": {"_id": str(i)}}) + "\n"
+                   + json.dumps(data.source(i)) + "\n"
+                   for i in data.ids[lo:hi].tolist())
+
+
 CASES = [
     ("msmarco-passage", ndjson_msmarco, ("bm25.serial",)),
     ("sift1m", ndjson_sift, ("knn.open",)),
+    ("msmarco-passage-4shard", ndjson_msmarco_4shard, ("bm25.mesh4.open",)),
 ]
 
 
@@ -43,9 +52,11 @@ def test_array_segments_serve_like_bulk(config, ndjson, traffics):
     srv = harness.Served()
     try:
         harness.create_index(srv, data.index, data.index_body())
-        harness.install_segments(srv, data.index, data.build_segments)
+        harness.install_shards(srv, data.index, data)
         harness.create_index(srv, "bulk", data.index_body())
-        per_seg = DOCS // int(cfg["segments"])
+        # one bulk and refresh per segment: a segment's docs all route
+        # to its shard, so each shard gets the same segments in order
+        per_seg = DOCS // int(cfg["segments"]) // int(cfg.get("shards", 1))
         for lo in range(0, DOCS, per_seg):
             out = srv.ok("POST", "/bulk/_bulk", ndjson(data, lo, lo + per_seg),
                          ndjson=True)
@@ -88,3 +99,15 @@ def test_reference_agrees_with_served_scores():
     assert got["rank_gap"] < 1e-5 and got["score_gap"] < 1e-5, got
     assert got["fetch_errors"] == 0 and got["lost"] == 0, got
     assert np.isfinite(got["score_gap"])
+
+
+def test_routing_hash_is_the_bulk_routing():
+    """The four-shard configuration's vectorized murmur3 routes every
+    ``_id`` to the shard ``_bulk`` routes it to."""
+    from elasticsearch_tpu.utils.murmur3 import shard_id_for
+    mod = load_module(HERE / "configs" / "msmarco-passage-4shard.py",
+                      "cfg_4shard")
+    n = np.arange(0, 200_000, 7)
+    got = mod.murmur3_decimal(n) % np.uint32(4)
+    want = [shard_id_for(str(i), 4) for i in n.tolist()]
+    assert got.tolist() == want
