@@ -184,7 +184,9 @@ def device_plane_stats() -> Dict[str, Any]:
     included), the quantized coarse tier's configured and SERVED
     re-rank depths (rerank_depth / rerank_depth_max /
     rerank_depth_histogram, with quantized_queries, rerank_lookups,
-    rerank_escalations, quantized_exact_fallbacks and the
+    the BM25 coarse pass's coarse_dense_terms, coarse_scatter_blocks
+    and coarse_dense_refused, rerank_escalations,
+    quantized_exact_fallbacks and the
     measured-latency engage rule's
     quantized_disengaged_slow), the drain-wide aggregation counters
     (plane_aggs_queries = specs served from a device partial,

@@ -19,12 +19,14 @@ idf follows the reference's BM25: ln(1 + (N - df + 0.5) / (df + 0.5)).
 
 from __future__ import annotations
 
+from collections import Counter
 from typing import Optional, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from elasticsearch_tpu.index.segment import PostingsField, next_pow2
 from elasticsearch_tpu.ops.device_segment import DevicePostings, gather_query_blocks
@@ -136,16 +138,105 @@ def bm25_flat_body(block_docs, block_tfs,
     return scores, matched
 
 
+# The dense head mirror (ops/device_segment.py ``HeadMirror``): a query
+# row reads at most DENSE_SLOTS of its terms as whole bf16 tf columns. The
+# count is fixed, so the coarse program's shapes gain no dimension; a row
+# with more head terms scatters the rest.
+DENSE_SLOTS = 8
+
+
+def tail_sizes(fb: int) -> list:
+    """Flat-plan prefixes the coarse kernel can scatter from a plan of
+    ``fb`` blocks (a ``qb_bucket``): 32 blocks, then 256 and x4 steps
+    above. The flat plan keeps the bucket of every block its rows read,
+    so the coarse programs are the ones a warm-up over posting work
+    meets; a dynamic branch then scatters the smallest prefix that holds
+    the tail, at most 4x its blocks above 256. Each prefix is a scatter
+    the program compiles: x4 keeps a 65,536-block plan at six."""
+    out, size = [], 32
+    while size < fb:
+        out.append(size)
+        size *= 8 if size < 256 else 4
+    return out + [fb]
+
+
+def coarse_contrib(w, tfs, dl, avgdl, k1: float, b: float):
+    """The coarse tier's bf16 BM25 contribution of tf ``tfs`` (bf16) for
+    docs of length ``dl`` (bf16) under ``avgdl`` and weight ``w`` (f32),
+    as f32. The one expression of the scatter and the dense passes, so
+    a posting contributes the same value whichever pass reads it."""
+    h = jnp.bfloat16
+    norm = h(k1) * (h(1.0 - b) + h(b) * dl / avgdl.astype(h))
+    return (w.astype(h) * tfs * h(k1 + 1.0) / (tfs + norm)) \
+        .astype(jnp.float32)
+
+
+def _coarse_scatter(scores, block_docs, block_tfs_q, flat_idx, flat_w,
+                    flat_q, doc_lens_q, flat_avgdl, n_docs_pad: int,
+                    k1: float, b: float):
+    """Gather the flat plan's blocks and scatter-add their contributions
+    into the flattened [n_q * n_docs_pad] f32 plane ``scores``."""
+    docs = block_docs[flat_idx]             # [FB, BLOCK]
+    tfs = block_tfs_q[flat_idx]             # [FB, BLOCK] bf16
+    valid = docs >= 0
+    safe = jnp.where(valid, docs, 0)
+    contrib = coarse_contrib(flat_w[:, None], tfs, doc_lens_q[safe],
+                             flat_avgdl[:, None], k1, b)
+    contrib = jnp.where(valid, contrib, 0.0)
+    tgt = flat_q[:, None] * n_docs_pad + safe
+    return scores.at[tgt.reshape(-1)].add(contrib.reshape(-1), mode="drop")
+
+
+def _dense_heads(dense, doc_lens_q, seg_ids, n_q: int, n_segs: int,
+                 k1: float, b: float):
+    """[n_q, n_docs_pad] f32 sum of each row's dense-slot contributions:
+    ``dense`` = (heads [H + 1, n_docs_pad] bf16 tf columns, row H all
+    zeros; slot_rows [n_q, DENSE_SLOTS] int32, H where unused; slot_w
+    [n_q, DENSE_SLOTS] f32 idf*boost; seg_avgdl [n_segs] f32, each
+    segment's effective avgdl). A doc norms against its own segment's
+    avgdl, which the scatter reads per block. Rows fill their slots
+    from the first, so only the slots some row uses are read."""
+    heads, slot_rows, slot_w, seg_avgdl = dense
+    avg = jnp.zeros(seg_ids.shape, jnp.float32)
+    for s in range(n_segs):
+        avg = jnp.where(seg_ids == s, seg_avgdl[s], avg)
+
+    def first(m):
+        def run():
+            acc = jnp.zeros((n_q, seg_ids.shape[0]), jnp.float32)
+            for j in range(m):
+                tfs = heads[slot_rows[:, j]]    # [n_q, n_docs_pad] bf16
+                c = coarse_contrib(slot_w[:, j, None], tfs,
+                                   doc_lens_q[None, :], avg[None, :], k1, b)
+                acc = acc + jnp.where(tfs > 0, c, 0.0)
+            return acc
+        return run
+    used = jnp.max(jnp.sum(slot_rows < heads.shape[0] - 1, axis=1))
+    return lax.switch(used, [first(m)
+                             for m in range(slot_rows.shape[1] + 1)])
+
+
 def bm25_coarse_body(block_docs, block_tfs_q, flat_idx, flat_w, flat_q,
                      doc_lens_q, flat_avgdl, live, seg_ids,
                      n_docs_pad: int, n_q: int, n_segs: int, kprime: int,
-                     k1: float = DEFAULT_K1, b: float = DEFAULT_B):
+                     k1: float = DEFAULT_K1, b: float = DEFAULT_B,
+                     tail=None, dense=None):
     """The quantized COARSE tier of the two-tier text path: the same
     gather/scatter shape as ``bm25_flat_body`` but over the plane's bf16
     mirrors (``block_tfs_q`` / ``doc_lens_q`` — half the HBM gather
     traffic, the scatter-bound classes' dominant cost), contributions
-    computed in bf16 and accumulated in f32. It is the only pass of the
-    two tiers that reads every posting of every query term.
+    computed in bf16 (``coarse_contrib``) and accumulated in f32. It is
+    the only pass of the two tiers that reads every posting of every
+    query term.
+
+    ``dense`` (see ``_dense_heads``) serves a row's heaviest terms from
+    whole tf columns, read contiguously, instead of scattering their
+    postings; the flat plan then holds only the other terms' blocks (the
+    tail), and only its prefix ``tail_sizes(FB)[tail]`` scatters
+    (``tail`` an int32 scalar). Each contribution is the scatter's value
+    bit for bit; only the order of the f32 adds differs. Without
+    ``tail`` the whole flat plan scatters (the mesh program's per-slot
+    body).
 
     Returns (coarse scores [n_q, kprime], candidate plane docs
     [n_q, kprime], per-segment match counts [n_q, n_segs]). The counts
@@ -157,21 +248,24 @@ def bm25_coarse_body(block_docs, block_tfs_q, flat_idx, flat_w, flat_q,
     candidate up in the query's term ranges and restores golden scores,
     and the k'-th coarse score bounds what any excluded doc could have
     scored (the adaptive-depth margin input)."""
-    docs = block_docs[flat_idx]             # [FB, BLOCK]
-    tfs = block_tfs_q[flat_idx]             # [FB, BLOCK] bf16
-    valid = docs >= 0
-    safe = jnp.where(valid, docs, 0)
-    dl = doc_lens_q[safe]                   # bf16
-    h = jnp.bfloat16
-    norm = h(k1) * (h(1.0 - b) + h(b) * dl
-                    / flat_avgdl.astype(h)[:, None])
-    contrib = flat_w.astype(h)[:, None] * tfs * h(k1 + 1.0) \
-        / (tfs + norm)
-    contrib = jnp.where(valid, contrib.astype(jnp.float32), 0.0)
-    tgt = flat_q[:, None] * n_docs_pad + safe
-    scores = jnp.zeros((n_q * n_docs_pad,), jnp.float32)
-    scores = scores.at[tgt.reshape(-1)].add(contrib.reshape(-1),
-                                            mode="drop")
+    if dense is None:
+        scores = jnp.zeros((n_q * n_docs_pad,), jnp.float32)
+    else:
+        scores = _dense_heads(dense, doc_lens_q, seg_ids, n_q, n_segs,
+                              k1, b).reshape(-1)
+
+    def prefix(size):
+        return lambda acc: _coarse_scatter(
+            acc, block_docs, block_tfs_q, flat_idx[:size], flat_w[:size],
+            flat_q[:size], doc_lens_q, flat_avgdl[:size], n_docs_pad, k1,
+            b)
+    if tail is None:
+        scores = prefix(flat_idx.shape[0])(scores)
+    else:
+        # a conditional, not a loop: a loop would carry (and copy) the
+        # whole block store on every call
+        scores = lax.switch(tail, [prefix(size) for size in
+                                   tail_sizes(flat_idx.shape[0])], scores)
     scores = scores.reshape(n_q, n_docs_pad)
     matched = live[None, :] & (scores > 0.0)
     scores = jnp.where(matched, scores, -jnp.inf)
@@ -266,10 +360,11 @@ def _bm25_coarse_kernel(block_docs, block_tfs_q, flat_idx, flat_w, flat_q,
                         doc_lens_q, flat_avgdl, live, seg_ids,
                         n_docs_pad: int, n_q: int, n_segs: int,
                         kprime: int, k1: float = DEFAULT_K1,
-                        b: float = DEFAULT_B):
+                        b: float = DEFAULT_B, tail=None, dense=None):
     return bm25_coarse_body(block_docs, block_tfs_q, flat_idx, flat_w,
                             flat_q, doc_lens_q, flat_avgdl, live, seg_ids,
-                            n_docs_pad, n_q, n_segs, kprime, k1=k1, b=b)
+                            n_docs_pad, n_q, n_segs, kprime, k1=k1, b=b,
+                            tail=tail, dense=dense)
 
 
 @profiled_jit("bm25_rerank", static_argnames=("k", "k1", "b"))
@@ -410,6 +505,49 @@ def qb_bucket(n: int, minimum: int = 32) -> int:
     while b < n:
         b *= 8 if b < 16384 else 2
     return b
+
+
+def dense_slots(rows, mirror, n_q_pad: int):
+    """Split each row plan between the dense head mirror and the scatter.
+
+    A row's term (its ranges with one weight) goes dense when it is a
+    head term of ``mirror`` (ops/device_segment.py ``HeadMirror``) whose
+    every segment range carries that weight the same number of times m:
+    it then takes m slots, heaviest terms first, while DENSE_SLOTS last;
+    the rest stay in the scatter. Returns (tail row plans, slot_rows
+    [n_q_pad, DENSE_SLOTS] int32 with ``mirror.n_heads`` where unused,
+    slot_w [n_q_pad, DENSE_SLOTS] f32, slots used)."""
+    slot_rows = np.full((n_q_pad, DENSE_SLOTS), mirror.n_heads, np.int32)
+    slot_w = np.zeros((n_q_pad, DENSE_SLOTS), np.float32)
+    tails, used = [], 0
+    for qi, p in enumerate(rows):
+        starts, counts, weights = p.ranges
+        groups: dict = {}
+        for j, (st, wt) in enumerate(zip(starts.tolist(),
+                                         weights.tolist())):
+            h = mirror.row_of_start.get(st)
+            if h is not None:
+                groups.setdefault((h, wt), []).append(j)
+        keep = np.ones(len(starts), bool)
+        n = 0
+        for (h, wt), js in sorted(groups.items()):
+            per = Counter(int(starts[j]) for j in js)
+            m = len(js) // len(per)
+            if set(per) != mirror.starts_of_row[h] or \
+                    any(c != m for c in per.values()) or \
+                    n + m > DENSE_SLOTS:
+                continue
+            slot_rows[qi, n: n + m] = h
+            slot_w[qi, n: n + m] = wt
+            keep[js] = False
+            n += m
+        used += n
+        if n:
+            mask = np.repeat(keep, counts)
+            p = QueryPlan(p.idx[mask], p.w[mask], p.ub[mask],
+                          p.other_ub[mask])
+        tails.append(p)
+    return tails, slot_rows, slot_w, used
 
 
 _NO_RANGES = (np.zeros(0, np.int32), np.zeros(0, np.int32),

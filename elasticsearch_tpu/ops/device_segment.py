@@ -245,6 +245,40 @@ def _seg_ids_host(doc_base, n_segs: int, length: int) -> np.ndarray:
     return np.maximum(ids, 0).astype(np.int32)
 
 
+# A term's postings go dense (one bf16 tf column over every plane doc)
+# when they cover at least 1/HEAD_DF_SHARE of the plane's padded docs.
+# Scattering a posting costs a single-element gather and scatter; a
+# column reads 2 bytes a doc contiguously. At 1/16 the dense read is at
+# most 32 bytes per posting it replaces, and on a zipfian vocabulary the
+# few such terms hold nearly all the postings a query reads (at 2^22
+# docs, zipf 1.05 over 2^17 terms: 73 terms, ~98% of the postings). A
+# second rule caps the mirror's bytes at the plane's own posting bytes
+# (``PlanePostings._head_terms``), so it at most doubles the plane's
+# residency.
+HEAD_DF_SHARE = 16
+
+
+class HeadMirror:
+    """The dense head mirror of a postings plane. ``cols`` [H + 1,
+    n_docs_pad] bf16 (device): row h holds head term ``terms[h]``'s tf
+    in every plane doc, the bf16 values the quantized mirror holds, and
+    row H is all zeros (the unused slot). ``row_of_start`` maps the plane
+    block where a head term's run starts in a segment to its row, and
+    ``starts_of_row[h]`` holds every such start of row h: a query plan
+    whose ranges cover them all may read the row instead."""
+
+    __slots__ = ("cols", "terms", "n_heads", "row_of_start",
+                 "starts_of_row")
+
+    def __init__(self, cols, terms: List[str],
+                 row_of_start: Dict[int, int], starts_of_row: list):
+        self.cols = cols
+        self.terms = terms
+        self.n_heads = len(terms)
+        self.row_of_start = row_of_start
+        self.starts_of_row = starts_of_row
+
+
 class PlanePostings(PlanePart):
     """All segments' posting blocks for one text field, doc ids rebased.
 
@@ -306,6 +340,8 @@ class PlanePostings(PlanePart):
         self.block_avgdl = ba
         self._q_dev: Optional[Tuple] = None
         self._q_failed = False
+        self._heads: Any = False        # not built yet
+        self.heads_refused = False
         return (bd, bt, dl_all)
 
     def upload(self, host) -> None:
@@ -340,6 +376,73 @@ class PlanePostings(PlanePart):
         self.nbytes += charge.n_bytes
         self._q_dev = (jnp.asarray(tf16), jnp.asarray(dl16))
         return self._q_dev
+
+    def head_mirror(self) -> Optional["HeadMirror"]:
+        """The dense head mirror of the coarse tier (``HeadMirror``), or
+        None where no term is heavy enough or the device breaker refused
+        it (``heads_refused``). Built like ``quantized_mirror``: on the
+        first coarse query, once per plane generation, charged on its
+        own, a refusal remembered."""
+        if self._heads is not False:
+            return self._heads
+        self._heads = None
+        terms = self._head_terms()
+        if not terms:
+            return None
+        n_bytes = (len(terms) + 1) * self.n_docs_pad * 2
+        from elasticsearch_tpu.indices.breaker import charge_device
+        try:
+            charge = charge_device(self, n_bytes,
+                                   f"plane_postings_heads:{self.field}",
+                                   return_charge=True)
+        except CircuitBreakingError:
+            self.heads_refused = True
+            return None
+        cols = np.zeros((len(terms) + 1, self.n_docs_pad), jnp.bfloat16)
+        row_of_start: Dict[int, int] = {}
+        starts_of_row = []
+        for h, term in enumerate(terms):
+            starts = set()
+            for pos, pf, block_base, _avgdl in self.refs:
+                start, count = pf.term_blocks(term)
+                if not count:
+                    continue
+                r_docs, r_tfs = self._seg_cache[self.segments[pos].uid][:2]
+                docs = r_docs[start: start + count].reshape(-1)
+                valid = docs >= 0
+                cols[h, docs[valid]] = \
+                    r_tfs[start: start + count].reshape(-1)[valid]
+                row_of_start[block_base + start] = h
+                starts.add(block_base + start)
+            starts_of_row.append(frozenset(starts))
+        self._charges.append(charge)
+        self.nbytes += charge.n_bytes
+        self._heads = HeadMirror(jnp.asarray(cols), terms, row_of_start,
+                                 starts_of_row)
+        return self._heads
+
+    def _head_terms(self) -> List[str]:
+        """The plane's head terms, heaviest first: every term whose
+        postings cover at least 1/HEAD_DF_SHARE of the padded docs, while
+        the mirror's bytes stay within the plane's own posting bytes."""
+        floor = self.n_docs_pad / HEAD_DF_SHARE
+        df: Dict[str, int] = {}
+        for _pos, pf, _base, _avgdl in self.refs:
+            names = list(pf.terms)
+            ids = np.fromiter(pf.terms.values(), np.int64, len(names))
+            # a term at the floor over the plane reaches floor / segments
+            # in one of them at least
+            for i in np.flatnonzero(pf.doc_freq[ids]
+                                    >= floor / len(self.refs)):
+                df[names[i]] = 0
+        for term in df:
+            df[term] = sum(int(pf.doc_freq[pf.terms[term]])
+                           for _pos, pf, _b, _a in self.refs
+                           if term in pf.terms)
+        heads = sorted((t for t, d in df.items() if d >= floor),
+                       key=lambda t: (-df[t], t))
+        posting_bytes = self.block_docs.nbytes + self.block_tfs.nbytes
+        return heads[: max(posting_bytes // (2 * self.n_docs_pad) - 1, 0)]
 
     def seg_ids(self) -> jnp.ndarray:
         """[n_docs_pad] int32: each plane doc's owning segment POSITION
@@ -740,6 +843,13 @@ class PlaneRegistry:
             # (query, term range, candidate) lookups the exact BM25
             # re-rank issued, one per real range and candidate per depth
             "rerank_lookups": 0,
+            # the BM25 coarse pass: query terms read from the dense head
+            # mirror and real tail blocks scattered, per dispatch, and
+            # drains that scattered everything because the breaker
+            # refused the mirror
+            "coarse_dense_terms": 0,
+            "coarse_scatter_blocks": 0,
+            "coarse_dense_refused": 0,
             "rerank_escalations": 0,
             "quantized_exact_fallbacks": 0,
             "quantized_disengaged_slow": 0,

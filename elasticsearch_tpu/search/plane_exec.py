@@ -165,7 +165,11 @@ def _coarse_wand_topk(part, per_seg, has_terms, n_q: int, live,
     the FULL (unpruned) plans — no WAND host planning, no theta sync, no
     recount — plus ONE exact f32 re-rank that looks the top-k'
     candidates up in each query's term ranges (``rerank_lookups``
-    counts the lookups), with the adaptive-depth margin loop. Totals
+    counts the lookups), with the adaptive-depth margin loop. The
+    coarse dispatch reads each query's head terms from the plane's
+    dense head mirror and scatters the rest (``coarse_dense_terms``,
+    ``coarse_scatter_blocks``; ``coarse_dense_refused`` counts drains
+    that scattered everything because the breaker refused it). Totals
     come EXACT off the coarse pass's per-segment counts (both the
     counts-then-skip and the totals-disabled contracts), so no mode
     needs a second counting dispatch. Returns plane_wand_topk's
@@ -175,7 +179,8 @@ def _coarse_wand_topk(part, per_seg, has_terms, n_q: int, live,
     plane_quantized_fallback)."""
     from elasticsearch_tpu.ops.bm25 import (
         MAX_BATCH_CELLS, MAX_CHUNK_Q, _bm25_coarse_kernel,
-        _bm25_rerank_kernel, flatten_plans, qb_bucket, rerank_ranges,
+        _bm25_rerank_kernel, dense_slots, flatten_plans, qb_bucket,
+        rerank_ranges, tail_sizes,
     )
     depth0 = _coarse_depth0(k_plane, part.n_docs_pad)
     if part.n_docs_total <= 4 * depth0:
@@ -192,8 +197,26 @@ def _coarse_wand_topk(part, per_seg, has_terms, n_q: int, live,
         return None
     tf16, dl16 = mirror
     n_q_pad = next_pow2(max(n_q, 1), minimum=1)
+    n_segs = len(part.segments)
+    heads = part.head_mirror()
+    tails, dense, n_dense = rows, None, 0
+    if heads is not None:
+        tails, slot_rows, slot_w, n_dense = dense_slots(rows, heads,
+                                                        n_q_pad)
+        # each segment's effective avgdl, DFS override included
+        seg_avg = np.ones(n_segs, np.float32)
+        for pos, _pf, block_base, _avgdl in part.refs:
+            seg_avg[pos] = eff_block_avgdl[block_base]
+        dense = (heads.cols, jnp.asarray(slot_rows), jnp.asarray(slot_w),
+                 jnp.asarray(seg_avg))
+    elif part.heads_refused:
+        PLANES.stats["coarse_dense_refused"] += 1
+    n_tail = sum(p.n_blocks for p in tails)
+    # the bucket of every block the rows read, as without the mirror: the
+    # kernel scatters the smallest prefix of it that holds the tail
     fb = qb_bucket(max(cells, 1))
-    idx, w, qid = flatten_plans(rows, fb)
+    idx, w, qid = flatten_plans(tails, fb)
+    tail = np.int32(np.searchsorted(tail_sizes(fb), n_tail))
     flat_avg = eff_block_avgdl[idx].astype(np.float32)
     idx_dev = jnp.asarray(idx)
     w_dev = jnp.asarray(w)
@@ -203,7 +226,6 @@ def _coarse_wand_topk(part, per_seg, has_terms, n_q: int, live,
                   rerank_ranges(rows, n_q_pad, eff_block_avgdl)]
     n_ranges = sum(len(p.ranges[0]) for p in rows)
     seg_ids = part.seg_ids()
-    n_segs = len(part.segments)
     blocks_total = [rows[qi].n_blocks for qi in range(n_q)]
 
     from elasticsearch_tpu.indices.breaker import BREAKERS
@@ -217,6 +239,8 @@ def _coarse_wand_topk(part, per_seg, has_terms, n_q: int, live,
                 counter.extend((1, 1))
             telemetry.record_dispatch(2)
             PLANES.stats["rerank_lookups"] += n_ranges * kprime
+            PLANES.stats["coarse_dense_terms"] += n_dense
+            PLANES.stats["coarse_scatter_blocks"] += n_tail
             # the coarse pass's f32 score plane and its masks
             transient = 8 * part.n_docs_pad * n_q_pad
             with BREAKERS.breaker("request").limit_scope(
@@ -224,7 +248,8 @@ def _coarse_wand_topk(part, per_seg, has_terms, n_q: int, live,
                 cs, cand, hits = _bm25_coarse_kernel(
                     part.block_docs, tf16, idx_dev, w_dev, qid_dev, dl16,
                     favg_dev, live, seg_ids, part.n_docs_pad, n_q_pad,
-                    n_segs, kprime, k1=DEFAULT_K1, b=DEFAULT_B)
+                    n_segs, kprime, k1=DEFAULT_K1, b=DEFAULT_B,
+                    tail=tail, dense=dense)
                 s, d, eps = _bm25_rerank_kernel(
                     part.block_docs, part.block_tfs, *ranges_dev,
                     part.doc_lens, live, cand, cs, k_plane,

@@ -102,6 +102,25 @@ def test_bm25_coarse_and_rerank_compile(one_chip):
              s((Q, kprime), jnp.int32), s((Q, kprime), jnp.float32), k=K)
 
 
+def test_bm25_coarse_with_dense_heads_compiles(one_chip):
+    """The coarse kernel with the dense head mirror's columns and the
+    tail's scatter branches."""
+    from elasticsearch_tpu.ops.bm25 import DENSE_SLOTS, _bm25_coarse_kernel
+    fb, kprime, n_segs, heads = 65536, 128, 4, 74
+    s = lambda shape, dt: _spec(shape, dt, one_chip)  # noqa: E731
+    _compile(_bm25_coarse_kernel,
+             s((NB, BLOCK), jnp.int32), s((NB, BLOCK), jnp.bfloat16),
+             s((fb,), jnp.int32), s((fb,), jnp.float32), s((fb,), jnp.int32),
+             s((N_DOCS,), jnp.bfloat16), s((fb,), jnp.float32),
+             s((N_DOCS,), jnp.bool_), s((N_DOCS,), jnp.int32),
+             n_docs_pad=N_DOCS, n_q=1, n_segs=n_segs, kprime=kprime,
+             tail=s((), jnp.int32),
+             dense=(s((heads + 1, N_DOCS), jnp.bfloat16),
+                    s((1, DENSE_SLOTS), jnp.int32),
+                    s((1, DENSE_SLOTS), jnp.float32),
+                    s((n_segs,), jnp.float32)))
+
+
 @pytest.mark.parametrize("masked", [False, True], ids=["plain", "masked"])
 def test_knn_topk_batch_compiles(one_chip, masked):
     from elasticsearch_tpu.ops.knn import (
